@@ -38,10 +38,19 @@ class PartitionTables:
 
 
 class StreamingPartitioner:
+    """node_cap / edge_cap / repl_cap are the per-part slot budgets of the
+    device tables the rows address (PipelineConfig); an allocation past
+    one raises here, on the host, naming the part and the cap — on the
+    device the slot would land in the next part's rows or be dropped.
+    None leaves that budget unchecked."""
+
     def __init__(self, n_parts: int, max_nodes: int, method: str = "hdrf",
                  bal: float = 2.0, eps: float = 1.0, seed: int = 0,
-                 chunk: int = 1024):
+                 chunk: int = 1024, node_cap: int | None = None,
+                 edge_cap: int | None = None, repl_cap: int | None = None):
         self.method = method
+        self.caps = {"node_cap": node_cap, "edge_cap": edge_cap,
+                     "repl_cap": repl_cap}
         self.bal = bal
         self.eps = eps
         self.chunk = chunk
@@ -112,8 +121,7 @@ class StreamingPartitioner:
                 u, v = int(u), int(v)
                 su = self._ensure_vertex(u, p)
                 sv = self._ensure_vertex(v, p)
-                es = t.next_eslot[p]
-                t.next_eslot[p] += 1
+                es = self._alloc(t.next_eslot, p, "edge_cap")
                 e_rows["part"].append(p)
                 e_rows["edge_slot"].append(es)
                 e_rows["src_slot"].append(su)
@@ -142,8 +150,7 @@ class StreamingPartitioner:
         slot = t.slot_of.get(key)
         if slot is not None:
             return slot
-        slot = int(t.next_vslot[part])
-        t.next_vslot[part] += 1
+        slot = self._alloc(t.next_vslot, part, "node_cap")
         t.slot_of[key] = slot
         t.replicas[vid, part] = True
         first = t.master[vid] < 0
@@ -163,9 +170,19 @@ class StreamingPartitioner:
         return slot
 
     def _alloc_repl(self, master_part: int) -> int:
-        c = int(self._repl_counters[master_part])
-        self._repl_counters[master_part] += 1
-        return c
+        return self._alloc(self._repl_counters, master_part, "repl_cap")
+
+    def _alloc(self, counters: np.ndarray, part: int, cap_name: str) -> int:
+        """Next free slot of `part` in a per-part counter, checked against
+        the named cap."""
+        slot = int(counters[part])
+        cap = self.caps[cap_name]
+        if cap is not None and slot >= cap:
+            raise RuntimeError(
+                f"part {part} ran out of slots: {cap_name}={cap} is full "
+                f"— raise PipelineConfig.{cap_name}")
+        counters[part] += 1
+        return slot
 
     # --------------------------------------------------------- feature path
     def locate_master(self, vid: int, create: bool = True):

@@ -114,8 +114,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import events as ev
 from repro.core import state as st
@@ -550,9 +549,9 @@ class D3Pipeline:
         self.layers = list(model.layers)
         self.params = params
         self.part = StreamingPartitioner(
-            cfg.n_parts, cfg.max_nodes, method=cfg.partitioner, seed=cfg.seed)
-        self.topo = st.init_topo(cfg.n_parts, cfg.edge_cap, cfg.repl_cap,
-                                 cfg.node_cap)
+            cfg.n_parts, cfg.max_nodes, method=cfg.partitioner,
+            seed=cfg.seed, node_cap=cfg.node_cap, edge_cap=cfg.edge_cap,
+            repl_cap=cfg.repl_cap)
         dims = [l.in_dim for l in self.layers] + [self.layers[-1].out_dim]
         # every resolved per-tick budget, incl. the routing-plane
         # backpressure rings sized per lane from the LOCAL (per-device)
@@ -566,27 +565,56 @@ class D3Pipeline:
             self._n_rounds = len(self.layers) // S
             self.rounds = (StagedActLayer(
                 base=replace(self.layers[0], act=False)),) * self._n_rounds
-            d = dims[0]
-            proto = st.init_layer(cfg.n_parts, cfg.node_cap, d, d,
-                                  bc_defer_rows=bc_rows,
-                                  rmi_defer_rows=rmi_rows)
-            # round r's state stacks layers r*S+0 .. r*S+S-1 over a
-            # leading stage axis (all layers initialize identically)
-            self.states = [jax.tree.map(lambda a: jnp.stack([a] * S), proto)
-                           for _ in range(self._n_rounds)]
         else:
             self._n_rounds = len(self.layers)
             self.rounds = None
-            self.states = [st.init_layer(cfg.n_parts, cfg.node_cap, dims[i],
-                                         dims[i], bc_defer_rows=bc_rows,
-                                         rmi_defer_rows=rmi_rows)
-                           for i in range(len(self.layers))]
         self.d_out = dims[-1]
-        self.sink = jnp.zeros((cfg.n_parts, cfg.node_cap, self.d_out))
-        self.sink_seen = jnp.zeros((cfg.n_parts, cfg.node_cap), bool)
-        self.queries = init_query_state(
-            cfg.n_parts, cfg.query_cap, self.d_out,
-            wire_defer_rows=caps.query_defer_rows)
+        # inter-stage ring: one fixed packed-FeatBatch slot shape carries
+        # both the host inbox (feat_cap rows) and any round's outbox
+        # (p_loc * cap_pp rows) between stages
+        cap_pp = caps.outbox_per_part
+        self._ring_caps = (max(cfg.feat_cap, p_loc * cap_pp), dims[0] + 3)
+
+        def fresh_tables():
+            topo = st.init_topo(cfg.n_parts, cfg.edge_cap, cfg.repl_cap,
+                                cfg.node_cap)
+            if S > 1:
+                d = dims[0]
+                proto = st.init_layer(cfg.n_parts, cfg.node_cap, d, d,
+                                      bc_defer_rows=bc_rows,
+                                      rmi_defer_rows=rmi_rows)
+                # round r's state stacks layers r*S+0 .. r*S+S-1 over a
+                # leading stage axis (all layers initialize identically)
+                states = [jax.tree.map(lambda a: jnp.stack([a] * S), proto)
+                          for _ in range(self._n_rounds)]
+            else:
+                states = [st.init_layer(cfg.n_parts, cfg.node_cap, dims[i],
+                                        dims[i], bc_defer_rows=bc_rows,
+                                        rmi_defer_rows=rmi_rows)
+                          for i in range(len(self.layers))]
+            queries = init_query_state(
+                cfg.n_parts, cfg.query_cap, self.d_out,
+                wire_defer_rows=caps.query_defer_rows)
+            ring = (jnp.zeros((S, self._n_rounds, n_dev * self._ring_caps[0],
+                               self._ring_caps[1]), jnp.float32)
+                    if S > 1 else None)
+            return (topo, states,
+                    jnp.zeros((cfg.n_parts, cfg.node_cap, self.d_out)),
+                    jnp.zeros((cfg.n_parts, cfg.node_cap), bool), queries,
+                    ring)
+
+        if mesh is None:
+            tables = fresh_tables()
+        else:
+            # each device zeroes its own shards: the whole carry never
+            # lands on one device on its way onto the mesh
+            sh = (stage_carry_shardings(mesh, self._n_rounds) if S > 1
+                  else carry_shardings(mesh, len(self.layers)))
+            tables = jax.jit(fresh_tables, out_shardings=(
+                sh.topo, list(sh.layers), sh.sink, sh.sink_seen, sh.queries,
+                sh.stage_ring))()
+        (self.topo, self.states, self.sink, self.sink_seen, self.queries,
+         self.stage_ring) = tables
         # the training plane's device state: labels/dirty window, live
         # params, per-part optimizer state (core/train_plane.py)
         self.train_state = (init_train_state(
@@ -595,32 +623,7 @@ class D3Pipeline:
             params["head"], train) if train is not None else None)
         self._acts = tuple(
             1.0 if getattr(l, "act", False) else 0.0 for l in self.layers)
-        # inter-stage ring: one fixed packed-FeatBatch slot shape carries
-        # both the host inbox (feat_cap rows) and any round's outbox
-        # (p_loc * cap_pp rows) between stages
-        cap_pp = caps.outbox_per_part
-        self._ring_caps = (max(cfg.feat_cap, p_loc * cap_pp), dims[0] + 3)
-        self.stage_ring = (jnp.zeros(
-            (S, self._n_rounds, n_dev * self._ring_caps[0],
-             self._ring_caps[1]), jnp.float32) if S > 1 else None)
         self._wire_bytes_per_tick = self._static_wire_bytes(dims, n_dev, S)
-        if mesh is not None and S > 1:
-            sh = stage_carry_shardings(mesh, self._n_rounds)
-            self.topo = jax.device_put(self.topo, sh.topo)
-            self.states = [jax.device_put(s, sh.layers[i])
-                           for i, s in enumerate(self.states)]
-            self.sink = jax.device_put(self.sink, sh.sink)
-            self.sink_seen = jax.device_put(self.sink_seen, sh.sink_seen)
-            self.queries = jax.device_put(self.queries, sh.queries)
-            self.stage_ring = jax.device_put(self.stage_ring, sh.stage_ring)
-        elif mesh is not None:
-            sh = carry_shardings(mesh, len(self.layers))
-            self.topo = jax.device_put(self.topo, sh.topo)
-            self.states = [jax.device_put(s, sh.layers[i])
-                           for i, s in enumerate(self.states)]
-            self.sink = jax.device_put(self.sink, sh.sink)
-            self.sink_seen = jax.device_put(self.sink_seen, sh.sink_seen)
-            self.queries = jax.device_put(self.queries, sh.queries)
         if mesh is not None and self.train_state is not None:
             self.train_state = jax.device_put(
                 self.train_state, train_shardings(mesh, self.train_state))
@@ -1832,13 +1835,13 @@ def _tick_jit(layers, params, topo, states, sink, sink_seen, queries,
                     eb, rb, vb, qb, lb, ts, now)
     cp = carry_pspecs(len(layers))
     tspec = train_pspecs(ts) if tcfg is not None else P()
-    sharded = shard_map(
+    sharded = jax.shard_map(
         prog, mesh=mesh,
         in_specs=(P(), cp.topo, cp.layers, cp.sink, cp.sink_seen,
                   cp.queries, P(), P(), P(), P(), P(), P(), tspec, P()),
         out_specs=(cp.topo, cp.layers, cp.sink, cp.sink_seen, cp.queries,
                    stats_pspecs(len(layers)), P("data"), P(), tspec, P()),
-        check_rep=False)
+        check_vma=False)
     return sharded(params, topo, states, sink, sink_seen, queries, inbox,
                    eb, rb, vb, qb, lb, ts, now)
 
@@ -1898,12 +1901,59 @@ def _super_tick_scan(layers, params, carry: st.PipelineCarry, batches,
     cp = carry_pspecs(len(layers),
                       train=(train_pspecs(carry.train)
                              if tcfg is not None else None))
-    sharded = shard_map(scan_prog, mesh=mesh,
-                        in_specs=(P(), cp, P()),
-                        out_specs=(cp, stats_pspecs(len(layers)), P(),
-                                   P(None, "data"), P()),
-                        check_rep=False)
+    sharded = jax.shard_map(scan_prog, mesh=mesh,
+                            in_specs=(P(), cp, P()),
+                            out_specs=(cp, stats_pspecs(len(layers)), P(),
+                                       P(None, "data"), P()),
+                            check_vma=False)
     return sharded(params, carry, batches)
+
+
+def lower_super_tick(model, cfg: PipelineConfig, T: int, window=None,
+                     sharding=None, mesh=None):
+    """Lower the super-tick program that `D3Pipeline(model, params, cfg,
+    mesh=mesh)` (1-D mesh or none, no training plane) launches for T
+    micro-ticks — from shapes alone. Nothing is allocated, so a
+    configuration can be sized (`.compile().memory_analysis()`) before
+    its tables exist, and compiled for devices that are described but
+    not attached (`jax.experimental.topologies`): `sharding` places a
+    mesh-less program on one such device, `mesh` may be built from them.
+    `window` defaults to cfg.window; a drain flush launches the STREAMING
+    program."""
+    def build():
+        pipe = D3Pipeline(model, model.init(jax.random.key(0)), cfg)
+        carry = st.PipelineCarry(
+            topo=pipe.topo, layers=tuple(pipe.states), sink=pipe.sink,
+            sink_seen=pipe.sink_seen, queries=pipe.queries,
+            now=jnp.int32(0), quiet=jnp.int32(0))
+        empty = [None] * T
+        return (pipe.params, carry,
+                pipe._stage_super_batches(empty, empty, empty, empty))
+
+    args = jax.eval_shape(build)          # (params, carry, batches)
+    router, shardings = LocalRouter(cfg.n_parts), None
+    if mesh is not None:
+        n_dev = int(dict(mesh.shape)["data"])
+        assert cfg.route_cap is None or n_dev == 1, \
+            "the defer rings of a capped exchange are sized per mesh"
+        router = MeshRouter(cfg.n_parts, n_dev, route_cap=cfg.route_cap,
+                            pack_backend=cfg.delivery_backend,
+                            telemetry=cfg.telemetry)
+        rep = NamedSharding(mesh, P())
+        shardings = (jax.tree.map(lambda _: rep, args[0]),
+                     carry_shardings(mesh, len(model.layers)),
+                     jax.tree.map(lambda _: rep, args[2]))
+    elif sharding is not None:
+        shardings = jax.tree.map(lambda _: sharding, args)
+    if shardings is not None:
+        args = jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            args, shardings)
+    return _super_tick_scan.lower(
+        tuple(model.layers), *args, window or cfg.window,
+        cfg.capacities().outbox, router,
+        make_delivery(cfg.delivery_backend), mesh, cfg.delta_eps, None,
+        None, cfg.telemetry)
 
 
 # --------------------------------------------- hybrid-parallel pipeline
@@ -2071,7 +2121,7 @@ def _tick_jit_2d(rounds, params, topo, states, sink, sink_seen, queries,
     cp = stage_carry_pspecs(len(rounds))
     tspec = train_pspecs(ts) if tcfg is not None else P()
     pspec = jax.tree.map(lambda _: P("stage"), params)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         prog, mesh=mesh,
         in_specs=(pspec, cp.topo, cp.layers, cp.sink, cp.sink_seen,
                   cp.queries, cp.stage_ring, P(), P(), P(), P(), P(),
@@ -2079,7 +2129,7 @@ def _tick_jit_2d(rounds, params, topo, states, sink, sink_seen, queries,
         out_specs=(cp.topo, cp.layers, cp.sink, cp.sink_seen, cp.queries,
                    cp.stage_ring, stage_stats_pspecs(len(rounds)),
                    P("stage"), P("data"), P(), tspec, P()),
-        check_rep=False)
+        check_vma=False)
     return sharded(params, topo, states, sink, sink_seen, queries, ring,
                    inbox, eb, rb, vb, qb, lb, ts, now)
 
@@ -2142,9 +2192,10 @@ def _super_tick_scan_2d(rounds, params, carry: st.PipelineCarry, batches,
     cp = stage_carry_pspecs(R, train=(train_pspecs(carry.train)
                                       if tcfg is not None else None))
     pspec = jax.tree.map(lambda _: P("stage"), params)
-    sharded = shard_map(scan_prog, mesh=mesh,
-                        in_specs=(pspec, cp, P()),
-                        out_specs=(cp, stage_stats_pspecs(R), P("stage"),
-                                   P(), P(None, "data"), P()),
-                        check_rep=False)
+    sharded = jax.shard_map(scan_prog, mesh=mesh,
+                            in_specs=(pspec, cp, P()),
+                            out_specs=(cp, stage_stats_pspecs(R),
+                                       P("stage"), P(), P(None, "data"),
+                                       P()),
+                            check_vma=False)
     return sharded(params, carry, batches)

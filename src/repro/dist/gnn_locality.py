@@ -22,7 +22,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.graph import segment
@@ -192,9 +191,9 @@ def make_locality_train_step(model, n_classes: int, axes, mesh,
     batch_keys = ("x", "labels", "label_mask", "senders", "receivers",
                   "edge_mask", "send_idx", "send_mask")
     in_batch_specs = {k: P(axes_t) for k in batch_keys}
-    sharded = shard_map(shard_body, mesh=mesh,
-                        in_specs=(P(), in_batch_specs),
-                        out_specs=(P(), P()), check_rep=False)
+    sharded = jax.shard_map(shard_body, mesh=mesh,
+                            in_specs=(P(), in_batch_specs),
+                            out_specs=(P(), P()), check_vma=False)
 
     @jax.jit
     def step(params, opt_state, batch):

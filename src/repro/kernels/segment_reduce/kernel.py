@@ -4,7 +4,10 @@ Layout contract (prepared by ops.py):
   * messages [E_pad, d] sorted by destination, padded so that no BLOCK_E
     edge block spans two BLOCK_V output blocks;
   * seg_local [E_pad] — destination index *within* its output block
-    (BLOCK_V sentinel = padding row, contributes nothing);
+    (BLOCK_V sentinel = padding row, contributes nothing). The kernel
+    views it as [n_eblk, 1, BLOCK_E] so each grid step reads one
+    lane-dense row of ids: a 1-D [BLOCK_E] block is refused by Mosaic
+    for any BLOCK_E below XLA's 1024-element tiling of 1-D int arrays;
   * eblk_to_vblk [n_eblk] (scalar-prefetch) — which output tile each edge
     block accumulates into (non-decreasing);
   * first_visit [n_eblk] (scalar-prefetch) — 1 where this edge block is the
@@ -13,11 +16,13 @@ Layout contract (prepared by ops.py):
 Grid is 1-D over edge blocks; the output BlockSpec's index_map reads the
 scalar-prefetched eblk_to_vblk, so consecutive grid steps can revisit the
 same output tile and accumulate in VMEM (the standard TPU reduction
-pattern). The inner op is onehot^T @ msgs — an (BLOCK_V x BLOCK_E) x
-(BLOCK_E x d) matmul on the MXU with f32 accumulation.
+pattern). The inner op is onehot @ msgs — a (BLOCK_V x BLOCK_E) x
+(BLOCK_E x d) matmul on the MXU at HIGHEST precision, so the 0/1
+selection reproduces every f32 message exactly (the default precision
+would round them to bf16).
 
 VMEM budget per step: BLOCK_E*d (msgs) + BLOCK_V*d (out tile) + BLOCK_E
-(ids) floats. Defaults BLOCK_E=512, BLOCK_V=256, d<=512 stay well under
+(ids) floats. Defaults BLOCK_E=512, BLOCK_V=256, d<=1024 stay well under
 16 MB VMEM with MXU-aligned (multiple-of-128) matmul dims.
 """
 from __future__ import annotations
@@ -41,13 +46,14 @@ def _kernel(eblk_to_vblk, first_visit,      # scalar prefetch
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    seg = seg_ref[...]                                  # [BLOCK_E]
+    seg = seg_ref[...]                                  # [1, BLOCK_E]
     msgs = msg_ref[...]                                 # [BLOCK_E, d]
-    # one-hot [BLOCK_E, BLOCK_V]; padding rows (seg == block_v) select none
-    rows = jax.lax.broadcasted_iota(jnp.int32, (seg.shape[0], block_v), 1)
-    onehot = (rows == seg[:, None]).astype(msgs.dtype)
+    # one-hot [BLOCK_V, BLOCK_E]; padding rows (seg == block_v) select none
+    rows = jax.lax.broadcasted_iota(jnp.int32, (block_v, seg.shape[1]), 0)
+    onehot = (rows == seg).astype(msgs.dtype)
     out_ref[...] += jax.lax.dot_general(
-        onehot, msgs, (((0,), (0,)), ((), ())),
+        onehot, msgs, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=out_ref.dtype)
 
 
@@ -69,7 +75,7 @@ def segment_sum_kernel(msgs, seg_local, eblk_to_vblk, first_visit,
         num_scalar_prefetch=2,
         grid=(n_eblk,),
         in_specs=[
-            pl.BlockSpec((block_e,), lambda i, ev, fv: (i,)),
+            pl.BlockSpec((None, 1, block_e), lambda i, ev, fv: (i, 0, 0)),
             pl.BlockSpec((block_e, d), lambda i, ev, fv: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_v, d), lambda i, ev, fv: (ev[i], 0)),
@@ -79,7 +85,8 @@ def segment_sum_kernel(msgs, seg_local, eblk_to_vblk, first_visit,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_vblocks * block_v, d), msgs.dtype),
         interpret=interpret,
-    )(eblk_to_vblk, first_visit, seg_local, msgs)
+    )(eblk_to_vblk, first_visit, seg_local.reshape(n_eblk, 1, block_e),
+      msgs)
 
 
 def _mean_rows_kernel(sum_ref, cnt_ref, out_ref):

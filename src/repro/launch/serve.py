@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_arch
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def serve_lm(args):
@@ -72,6 +73,7 @@ def serve_stream(args):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="d3gnn-sage")
     ap.add_argument("--edges", type=int, default=2000)

@@ -48,6 +48,18 @@ def run_forced_devices(n: int, test_file, pytest_args=(), timeout=540):
         timeout=timeout)
 
 
+def load_chip_smoke():
+    """Import the repo-root `chip_smoke.py` script as a module (once)."""
+    import importlib.util
+    if "chip_smoke" not in sys.modules:
+        spec = importlib.util.spec_from_file_location(
+            "chip_smoke", REPO / "chip_smoke.py")
+        module = importlib.util.module_from_spec(spec)
+        sys.modules["chip_smoke"] = module
+        spec.loader.exec_module(module)
+    return sys.modules["chip_smoke"]
+
+
 @pytest.fixture(scope="session")
 def rng():
     return np.random.default_rng(0)

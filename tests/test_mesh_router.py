@@ -13,7 +13,8 @@ Three execution tiers:
     jax sees >= 4 devices, i.e. they run in the CI mesh lane
     (XLA_FLAGS=--xla_force_host_platform_device_count=4);
   * a subprocess smoke (fast lane, any environment) that forces a 4-device
-    CPU backend and checks the streaming golden triplet + backpressure;
+    CPU backend and checks the streaming golden triplet, backpressure and
+    the sharded table allocation;
     the slow lane re-runs the full @needs4 matrix the same way.
 """
 from pathlib import Path
@@ -175,6 +176,21 @@ def test_mesh_golden_matrix_multidevice(window):
 
 
 @needs4
+def test_mesh_tables_born_sharded():
+    """Each device zeroes its own shards of a mesh pipeline's tables: none
+    is built whole on one device and copied out, which at deployment caps
+    would hold the entire carry on device 0."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    mesh = make_stream_mesh(4)
+    with jax.transfer_guard_device_to_device("disallow_explicit"):
+        _, _, pipe = build_pipe(win.WindowConfig(kind=win.STREAMING),
+                                mesh=mesh)
+    assert pipe.states[0].feat.sharding == NamedSharding(mesh, P("data"))
+    assert pipe.sink.sharding == NamedSharding(mesh, P("data"))
+
+
+@needs4
 def test_mesh_outbox_backpressure_dropped():
     """Regression: a starved outbox (one emission slot per part per tick)
     must defer — not lose — emissions under the sharded path."""
@@ -243,9 +259,10 @@ def _run_forced4(pytest_args, timeout=540):
 
 def test_mesh_golden_streaming_forced4_subprocess():
     """Fast-lane smoke on any machine: force a 4-device CPU backend in a
-    subprocess and run the STREAMING golden + backpressure tests there."""
+    subprocess and run the STREAMING golden, backpressure and sharded
+    allocation tests there."""
     r = _run_forced4(["-k", "test_mesh_golden_matrix_multidevice and "
-                            "streaming or backpressure"])
+                            "streaming or backpressure or born_sharded"])
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-2000:]
 
 

@@ -108,3 +108,53 @@ def test_physical_busy_aggregation():
     agg = physical_busy(busy, 4, 8)
     assert agg.sum() == busy.sum()
     assert imbalance_factor(np.array([2.0, 2.0])) == 1.0
+
+
+@pytest.mark.parametrize("cap", ["node_cap", "edge_cap", "repl_cap"])
+def test_slot_overflow_raises_naming_part_and_cap(cap):
+    """An allocation past a per-part cap fails on the host, naming the
+    part and the cap — on the device the slot would spill into the next
+    part's rows (or be dropped for the last part)."""
+    rng = np.random.default_rng(0)
+    edges = powerlaw_edges(rng, 200, 1000)
+    roomy = dict(node_cap=1000, edge_cap=1000, repl_cap=1000)
+    roomy[cap] = 4
+    part = StreamingPartitioner(8, 200, **roomy)
+    with pytest.raises(RuntimeError, match=rf"part \d+ .*{cap}=4"):
+        part.ingest_edges(edges)
+
+
+def test_caps_admit_exactly_their_slots():
+    """A cap is a budget, not an off-by-one: a stream that needs exactly
+    cap slots in some part ingests cleanly."""
+    rng = np.random.default_rng(0)
+    edges = powerlaw_edges(rng, 200, 1000)
+    free = StreamingPartitioner(8, 200)
+    free.ingest_edges(edges)
+    t = free.t
+    tight = StreamingPartitioner(
+        8, 200, node_cap=int(t.next_vslot.max()),
+        edge_cap=int(t.next_eslot.max()),
+        repl_cap=int(free._repl_counters.max()))
+    tight.ingest_edges(edges)
+    assert (tight.t.next_vslot == t.next_vslot).all()
+
+
+def test_pipeline_passes_caps_to_partitioner():
+    """D3Pipeline hands its PipelineConfig caps to the partitioner, so a
+    stream that outgrows node_cap stops at the tick that would overflow."""
+    import jax
+
+    from repro.core.pipeline import D3Pipeline, PipelineConfig
+    from repro.graph.sage import GraphSAGE
+
+    rng = np.random.default_rng(0)
+    edges = powerlaw_edges(rng, 200, 1000)
+    model = GraphSAGE((4, 4))
+    cfg = PipelineConfig(n_parts=4, node_cap=8, edge_cap=512, repl_cap=512,
+                         feat_cap=64, edge_tick_cap=256, max_nodes=200)
+    pipe = D3Pipeline(model, model.init(jax.random.key(0)), cfg)
+    assert pipe.part.caps == {"node_cap": 8, "edge_cap": 512,
+                              "repl_cap": 512}
+    with pytest.raises(RuntimeError, match=r"part \d+ .*node_cap=8"):
+        pipe.tick(edges[:256])

@@ -222,7 +222,6 @@ def test_invalid_part_masked_out_of_exchange():
     destination clip shipped it to the LAST device."""
     from functools import partial
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.events import MsgBatch
@@ -247,9 +246,9 @@ def test_invalid_part_masked_out_of_exchange():
         return (out.part, out.valid, router.psum(rcpt.rows),
                 router.psum(rcpt.dropped))
 
-    f = shard_map(prog, mesh=mesh, in_specs=(),
-                  out_specs=(P("data"), P("data"), P(), P()),
-                  check_rep=False)
+    f = jax.shard_map(prog, mesh=mesh, in_specs=(),
+                      out_specs=(P("data"), P("data"), P(), P()),
+                      check_vma=False)
     parts, valid, rows, dropped = jax.jit(f)()
     parts, valid = np.asarray(parts), np.asarray(valid)
     # device 3 receives the four valid records; nothing else arrives
