@@ -1,5 +1,5 @@
-"""CI telemetry lane (ISSUE 9): record a short hub-heavy trace, fit the
-cost model, run the capacity advisor, and REPLAY its recommendation.
+"""CI telemetry lane: record a short hub-heavy trace, run the
+capacity advisor, and REPLAY its recommendation.
 
 Everything runs in one forced-4-device subprocess (the XLA host-platform
 device count is fixed at backend init, same pattern as bench_scaling):
@@ -8,11 +8,9 @@ device count is fixed at backend init, same pattern as bench_scaling):
      with the telemetry plane on and a DENSE exchange (route_cap=None —
      peaks recorded under a capped config reflect that config's deferral
      dynamics, see telemetry/advisor.py), saving TRACE.npz;
-  2. fit `telemetry/cost_model.py` on the trace and gate its accuracy:
-     predicted per-tick cost within 25% of measured on >= 80% of rows;
-  3. run `telemetry/advisor.py` -> RECS.json (caps already validated
+  2. run `telemetry/advisor.py` -> RECS.json (caps already validated
      against PipelineConfig.validate() by the advisor itself);
-  4. replay the SAME stream under the recommended caps and assert the
+  3. replay the SAME stream under the recommended caps and assert the
      acceptance bar: dropped == 0, route_dropped == 0, wire bytes <=
      the dense config, and a bit-identical sink.
 
@@ -38,8 +36,8 @@ from repro.core.pipeline import D3Pipeline, PipelineConfig
 from repro.graph.graphs import powerlaw_edges
 from repro.graph.sage import GraphSAGE
 from repro.launch.mesh import make_stream_mesh
-from repro.telemetry import (apply_recommendation, fit_cost_model,
-                             load_trace, recommend, replay_ok)
+from repro.telemetry import (apply_recommendation, load_trace, recommend,
+                             replay_ok)
 
 D = {n_devices}
 N_EDGES = {n_edges}
@@ -74,19 +72,12 @@ drive(dense)
 dense.save_trace(TRACE)
 trace = load_trace(TRACE)
 
-# 2. cost model accuracy gate (acceptance: 25% on >= 80% of rows)
-cm = fit_cost_model(trace)
-rep = cm.report(trace, tol=0.25)
-assert rep["n"] > 0, "cost model had no rows to score"
-assert rep["hit_frac"] >= 0.8, \
-    f"cost model off by >25% on too many rows: {{rep}}"
-
-# 3. advisor (bounds-checked inside recommend())
+# 2. advisor (bounds-checked inside recommend())
 recs = recommend(trace)
 with open(RECS, "w") as f:
     json.dump(recs, f, indent=2)
 
-# 4. replay the recommendation through the real pipeline
+# 3. replay the recommendation through the real pipeline
 cfg2 = apply_recommendation(
     PipelineConfig(n_parts=8, node_cap=128, edge_cap=1024, repl_cap=512,
                    max_nodes=n_nodes), recs)
@@ -98,8 +89,7 @@ assert pipe2._wire_bytes_per_tick <= dense._wire_bytes_per_tick, \
 np.testing.assert_array_equal(np.asarray(pipe2.sink),
                               np.asarray(dense.sink))
 print("RESULT,record_trace,"
-      f"{{len(trace)}},{{rep['hit_frac']:.3f}},{{rep['mae_frac']:.3f}},"
-      f"{{recs['caps']['route_cap']}},{{out['wire_bytes']}},"
+      f"{{len(trace)}},{{recs['caps']['route_cap']}},{{out['wire_bytes']}},"
       f"{{dense.metrics.wire_bytes}}")
 """
 
@@ -119,10 +109,8 @@ def run(trace: str, recs: str, n_devices: int = 4, n_edges: int = 960,
         raise RuntimeError("record_trace worker failed:\n" + r.stderr[-3000:])
     for line in r.stdout.splitlines():
         if line.startswith("RESULT,record_trace,"):
-            (_, _, ticks, hit, mae, route_cap, wire_rec,
-             wire_dense) = line.split(",")
-            return {"ticks": int(ticks), "hit_frac": float(hit),
-                    "mae_frac": float(mae),
+            _, _, ticks, route_cap, wire_rec, wire_dense = line.split(",")
+            return {"ticks": int(ticks),
                     "route_cap": None if route_cap == "None"
                     else int(route_cap),
                     "wire_bytes_recommended": int(wire_rec),

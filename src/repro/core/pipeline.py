@@ -87,9 +87,8 @@ per-part outbox demand peaks) and each tick emits one occupancy row
 that rides the super-tick scan's ys — still ONE host sync. On the host,
 every tick appends a row (device gauges + wall/staging timings + exact
 wire bytes + ingest counts) to `telemetry/trace.py:TraceRecorder`
-(`save_trace()` -> .npz) and feeds `ft/stragglers.py`; the cost model
-(`telemetry/cost_model.py`) and capacity advisor
-(`telemetry/advisor.py`) consume the trace offline. `telemetry=False`
+(`save_trace()` -> .npz) and feeds `ft/stragglers.py`; the capacity
+advisor (`telemetry/advisor.py`) consumes the trace offline. `telemetry=False`
 (default) keeps the gauges as static zeros — XLA dead-code-eliminates
 them and the program is bit-for-bit the five-plane tick.
 
@@ -105,7 +104,6 @@ Staging model / constraints:
 """
 from __future__ import annotations
 
-import time
 import warnings
 from dataclasses import dataclass, field, replace
 from functools import partial
@@ -132,6 +130,7 @@ from repro.dist.sharding import (carry_pspecs, carry_shardings,
                                  stage_stats_pspecs, stats_pspecs)
 from repro.dist.wire import field_col, pack_lane, pad_lane, unpack_lane
 from repro.ft.stragglers import StragglerMitigator
+from repro.telemetry.spans import SpanClock
 from repro.telemetry.trace import TRACE_DEVICE_COLS, TraceRecorder
 from repro.core.train_plane import (TrainConfig, init_train_state,
                                     train_pspecs, train_shardings,
@@ -446,11 +445,30 @@ class StreamMetrics:
                                        # demand — the cap binds per part,
                                        # so zero-drop needs outbox_cap >=
                                        # n_parts x outbox_part_peak
-    host_seconds: float = 0.0          # host-side staging time (per-tick
-                                       # driver only; the scan driver's
-                                       # staging amortizes into wall)
-    wall_seconds: float = 0.0
     busy_logical: Optional[np.ndarray] = None
+    # host spans (telemetry/spans.py): {name: SpanStat(count, total_s,
+    # self_s)} for d3.chunk, d3.launch, d3.stage, d3.stage.partition,
+    # d3.stage.pack, d3.dispatch, d3.sync, d3.harvest and d3.drain
+    spans: dict = field(default_factory=dict)
+    # staging counters, one increment per staged launch
+    edges_staged: int = 0              # edges handed to the partitioner
+    feat_rows_staged: int = 0          # feature events handed to staging
+    feat_slots_uploaded: int = 0       # padded feature slots (feat_cap x T)
+    upload_bytes: int = 0              # nbytes of the batch leaves launched
+    launches: int = 0                  # device launches (ticks or scans)
+    drain_launches: int = 0            # of which flush launches
+
+    @property
+    def host_seconds(self) -> float:
+        """Host staging seconds: the d3.stage span's total."""
+        st = self.spans.get("d3.stage")
+        return st.total_s if st is not None else 0.0
+
+    @property
+    def wall_seconds(self) -> float:
+        """Launch wall seconds: the d3.launch span's total."""
+        st = self.spans.get("d3.launch")
+        return st.total_s if st is not None else 0.0
 
     @property
     def throughput(self) -> float:
@@ -630,6 +648,7 @@ class D3Pipeline:
         self.now = 0
         self.metrics = StreamMetrics(
             busy_logical=np.zeros(cfg.n_parts, np.int64))
+        self._clock = SpanClock(self.metrics.spans)
         self._empty_feat = ev.empty_feat_batch(cfg.feat_cap, dims[0])
         empty_rows = {k: np.zeros(0, np.int64) for k in
                       ("part", "edge_slot", "src_slot", "dst_slot",
@@ -648,14 +667,7 @@ class D3Pipeline:
         self._empty_labels_np = ev.label_batch_from_numpy(
             z0, z0, z0, cfg.train_cap, device=False)
         self._answer_log: list = []    # host-side answered-row columns
-        # telemetry plane (ISSUE 9): the trace recorder + straggler feed.
-        # The lane list / a2a multiplier let the cost model re-price wire
-        # bytes at candidate route_caps without re-deriving the lane
-        # arithmetic (same constants as _static_wire_bytes above).
-        lanes = self._wire_lane_list(dims, n_dev, S)
-        a2a_mult = (S * n_dev * n_dev * 4
-                    if mesh is not None and n_dev > 1 else 0)
-        a2a = a2a_mult * sum(self.router.lane_cap(c) * w for c, w in lanes)
+        # telemetry plane: the trace recorder + straggler feed
         if cfg.telemetry:
             from dataclasses import asdict
             self.trace = TraceRecorder(meta={
@@ -673,34 +685,11 @@ class D3Pipeline:
                 "query_tick_cap": cfg.query_tick_cap,
                 "train_cap": cfg.train_cap,
                 "caps": asdict(caps),
-                "wire_bytes_per_tick": self._wire_bytes_per_tick,
-                "wire_lanes": [list(l) for l in lanes],
-                "a2a_mult": a2a_mult,
-                "fixed_wire_bytes": self._wire_bytes_per_tick - a2a})
+                "wire_bytes_per_tick": self._wire_bytes_per_tick})
             self.straggler = StragglerMitigator(n_shards=max(n_dev, 1))
         else:
             self.trace = None
             self.straggler = None
-
-    def _wire_lane_list(self, dims, n_dev: int, n_stages: int = 1):
-        """The capped-exchange lanes of one tick as (local emission
-        capacity, wire width) pairs — the SAME constants
-        `_static_wire_bytes` prices (its a2a term is
-        a2a_mult * sum(lane_cap(c) * w)); recorded in the trace meta so
-        the cost model can replay wire bytes at a different route_cap."""
-        if self.mesh is None or n_dev <= 1:
-            return []
-        cfg = self.cfg
-        p_loc = cfg.n_parts // n_dev
-        lanes = []
-        n_lay = self._n_rounds if n_stages > 1 else len(self.layers)
-        for li in range(n_lay):
-            d = dims[0] if n_stages > 1 else dims[li]
-            lanes.append((p_loc * cfg.repl_cap, d + 5))
-            lanes.append((cfg.edge_tick_cap + p_loc * cfg.edge_cap, d + 5))
-        if cfg.query_cap > 0:
-            lanes.append((p_loc * cfg.query_cap, wire_width(self.d_out)))
-        return lanes
 
     def save_trace(self, path) -> None:
         """Write the recorded telemetry trace (needs cfg.telemetry)."""
@@ -1137,12 +1126,19 @@ class D3Pipeline:
         labels: [(vid, gold_class), ...] training-plane admissions —
         resolved to master coordinates; vids the partitioner has never
         seen are silently skipped (no master slot to label)."""
-        cfg = self.cfg
+        with self.span("d3.stage.partition"):
+            rows = self._partition(edges, feats)
+        with self.span("d3.stage.pack"):
+            return self._pack(*rows, device, queries, issue_tick, labels)
+
+    def _partition(self, edges, feats):
+        """The partitioner's share of staging: place the tick's edges
+        (HDRF), give every feature event its master slot (cold features
+        create vertices), and drain the replica and vertex allocations."""
         if edges is not None and len(edges):
             e_rows, r1, v1 = self.part.ingest_edges(edges)
         else:
             e_rows, r1, v1 = None, None, None
-        # feature events may create vertices (cold features)
         f_parts, f_slots, f_vecs = [], [], []
         if feats:
             coalesced = {}
@@ -1159,7 +1155,14 @@ class D3Pipeline:
             v_rows = {k: np.concatenate([v1[k], v2[k]]) for k in v2}
         else:
             r_rows, v_rows = r2, v2
+        return e_rows, r_rows, v_rows, (f_parts, f_slots, f_vecs)
 
+    def _pack(self, e_rows, r_rows, v_rows, f_rows, device, queries,
+              issue_tick, labels):
+        """Pad the partitioned rows into the tick's fixed-capacity
+        batches (queries and labels resolved to master slots here)."""
+        cfg = self.cfg
+        f_parts, f_slots, f_vecs = f_rows
         eb = (ev.edge_batch_from_numpy(e_rows, cfg.edge_tick_cap, device)
               if e_rows is not None
               else (self._empty_edges if device else self._empty_edges_np))
@@ -1215,61 +1218,76 @@ class D3Pipeline:
         """
         cfg = self.cfg
         wconf = window or cfg.window
-        t0 = time.perf_counter()
         tick0 = self.now
         outbox_cap = cfg.capacities().outbox
-        eb, rb, vb, fb, qb, lb = self._build_batches(edges, feats,
-                                                     queries=queries,
-                                                     labels=labels)
-        host_s = time.perf_counter() - t0   # host-side staging round timer
         counts = (len(edges) if edges is not None else 0,
                   len(feats) if feats else 0,
                   len(queries) if queries else 0,
                   len(labels) if labels else 0)
-        now = jnp.asarray(self.now, jnp.int32)
-        if self.n_stages > 1:
-            (self.topo, new_states, self.sink, self.sink_seen,
-             self.queries, self.stage_ring, stats_all, idle, answers,
-             qstats, new_ts, occ) = _tick_jit_2d(
-                self.rounds, self._staged_params(), self.topo,
-                tuple(self.states), self.sink, self.sink_seen,
-                self.queries, self.stage_ring, fb, eb, rb, vb, qb, lb,
-                self.train_state, now, wconf, outbox_cap, self.router,
-                self.delivery, self.mesh, cfg.delta_eps, self.train_cfg,
-                self._head, self._acts, cfg.telemetry)
+        self.metrics.launches += 1
+        with self.span("d3.launch", step=True) as launch:
+            s0 = self._clock.total("d3.stage")
+            with self.span("d3.stage"):
+                batches = self._build_batches(edges, feats, queries=queries,
+                                              labels=labels)
+            host_s = self._clock.total("d3.stage") - s0
+            eb, rb, vb, fb, qb, lb = batches
+            self._count_staged([counts], fb, batches)
+            now = jnp.asarray(self.now, jnp.int32)
+            if self.n_stages > 1:
+                with self.span("d3.dispatch"):
+                    (self.topo, new_states, self.sink, self.sink_seen,
+                     self.queries, self.stage_ring, stats_all, idle, answers,
+                     qstats, new_ts, occ) = _tick_jit_2d(
+                        self.rounds, self._staged_params(), self.topo,
+                        tuple(self.states), self.sink, self.sink_seen,
+                        self.queries, self.stage_ring, fb, eb, rb, vb, qb,
+                        lb, self.train_state, now, wconf, outbox_cap,
+                        self.router, self.delivery, self.mesh,
+                        cfg.delta_eps, self.train_cfg, self._head,
+                        self._acts, cfg.telemetry)
+                self.states = list(new_states)
+                self.train_state = new_ts
+                self._sync_params_from_train()
+                self.now += 1
+                with self.span("d3.sync"):
+                    stats_all, idle, qstats, answers, occ = jax.device_get(
+                        (stats_all, idle, qstats, answers, occ))
+                with self.span("d3.harvest"):
+                    self._harvest_answers(answers)
+                    per_layer = self._unstack_stats(stats_all)
+                    self.metrics.stage_idle += int(np.sum(idle))
+                    occ_np = (np.asarray(occ) if self.trace is not None
+                              else None)
+                    self._accumulate(per_layer, qstats=qstats,
+                                     occ_rows=occ_np)
+                    self._trace_ticks(occ_np, tick0, launch.elapsed(),
+                                      host_s, counts, per_layer)
+                return per_layer
+            with self.span("d3.dispatch"):
+                (self.topo, new_states, self.sink, self.sink_seen,
+                 self.queries, stats_all, answers, qstats, new_ts,
+                 occ) = _tick_jit(
+                    tuple(self.layers), self.params, self.topo,
+                    tuple(self.states), self.sink, self.sink_seen,
+                    self.queries, fb, eb, rb, vb, qb, lb, self.train_state,
+                    now, wconf, outbox_cap, self.router, self.delivery,
+                    self.mesh, cfg.delta_eps, self.train_cfg, self._head,
+                    cfg.telemetry)
             self.states = list(new_states)
             self.train_state = new_ts
             self._sync_params_from_train()
             self.now += 1
-            self._harvest_answers(answers)
-            per_layer = self._unstack_stats(jax.device_get(stats_all))
-            self.metrics.stage_idle += int(np.sum(jax.device_get(idle)))
-            dt = time.perf_counter() - t0
-            occ_np = (np.asarray(jax.device_get(occ))
-                      if self.trace is not None else None)
-            self._accumulate(per_layer, dt, qstats=qstats,
-                             occ_rows=occ_np)
-            self._trace_ticks(occ_np, tick0, dt, host_s, counts,
-                              per_layer)
-            return per_layer
-        (self.topo, new_states, self.sink, self.sink_seen, self.queries,
-         stats_all, answers, qstats, new_ts, occ) = _tick_jit(
-            tuple(self.layers), self.params, self.topo, tuple(self.states),
-            self.sink, self.sink_seen, self.queries, fb, eb, rb, vb, qb,
-            lb, self.train_state, now, wconf, outbox_cap, self.router,
-            self.delivery, self.mesh, cfg.delta_eps, self.train_cfg,
-            self._head, cfg.telemetry)
-        self.states = list(new_states)
-        self.train_state = new_ts
-        self._sync_params_from_train()
-        self.now += 1
-        self._harvest_answers(answers)
-        dt = time.perf_counter() - t0
-        occ_np = (np.asarray(jax.device_get(occ))
-                  if self.trace is not None else None)
-        self._accumulate(stats_all, dt, qstats=qstats, occ_rows=occ_np)
-        self._trace_ticks(occ_np, tick0, dt, host_s, counts, stats_all)
-        return list(stats_all)
+            with self.span("d3.sync"):
+                stats_all, qstats, answers, occ = jax.device_get(
+                    (stats_all, qstats, answers, occ))
+            with self.span("d3.harvest"):
+                self._harvest_answers(answers)
+                occ_np = np.asarray(occ) if self.trace is not None else None
+                self._accumulate(stats_all, qstats=qstats, occ_rows=occ_np)
+                self._trace_ticks(occ_np, tick0, launch.elapsed(), host_s,
+                                  counts, stats_all)
+            return list(stats_all)
 
     def _sync_params_from_train(self) -> None:
         """Mirror the live trained parameters back into `self.params` so
@@ -1326,7 +1344,7 @@ class D3Pipeline:
         return {k: np.concatenate([chunk[k] for chunk in log])
                 for k in log[0]}
 
-    def _accumulate(self, stats_all, dt, ticks: int = 1, qstats=None,
+    def _accumulate(self, stats_all, ticks: int = 1, qstats=None,
                     occ_rows=None):
         """Fold per-layer stats into StreamMetrics — one tick's stats from
         the per-tick driver, or `ticks` micro-ticks' summed stats from a
@@ -1337,7 +1355,6 @@ class D3Pipeline:
         the peak gauges fold with max (their scan SUM is meaningless)."""
         m = self.metrics
         m.ticks += ticks
-        m.wall_seconds += dt
         m.wire_bytes += ticks * self._wire_bytes_per_tick
         for s in stats_all:
             m.reduce_msgs += int(s.reduce_msgs)
@@ -1376,18 +1393,19 @@ class D3Pipeline:
         occ_rows: [ticks, C] device occupancy rows; counts: per-tick
         (edges, feats, queries, labels) ingest tuples — a single tuple on
         the per-tick driver, a list of `ticks` tuples on the scan driver
-        (whose wall time is attributed uniformly, amortized=1)."""
+        (whose wall and staging times are spread evenly over its ticks,
+        amortized=1)."""
         if self.trace is None:
             return
         occ = np.asarray(occ_rows).reshape(-1, len(TRACE_DEVICE_COLS))
         rows = [counts] if ticks == 1 else list(counts)
         per = wall_s / max(ticks, 1)
+        host_per = host_s / max(ticks, 1)
         for i in range(ticks):
             e, f, q, l = rows[i]
             self.trace.append(
                 {"tick": tick0 + i, "ticks": 1, "wall_s": per,
-                 "host_s": host_s if ticks == 1 else 0.0,
-                 "amortized": amortized,
+                 "host_s": host_per, "amortized": amortized,
                  "wire_bytes": self._wire_bytes_per_tick,
                  "edges_in": e, "feats_in": f, "queries_in": q,
                  "labels_in": l},
@@ -1398,7 +1416,22 @@ class D3Pipeline:
             busy += np.asarray(jax.device_get(s.busy), np.int64)
         shards = busy.reshape(max(self._n_data, 1), -1).sum(axis=1)
         self.straggler.observe_tick(per, shards)
-        self.metrics.host_seconds += host_s
+
+    def span(self, name: str, step: bool = False):
+        """A host span of the current launch (`telemetry/spans.py`),
+        totalled into `metrics.spans`."""
+        return self._clock.span(name, self.metrics.launches, step)
+
+    def _count_staged(self, counts, fb, batches) -> None:
+        """Fold one launch's staged batches into the staging counters:
+        `counts` holds a (edges, feats, queries, labels) tuple per tick,
+        `fb` is the launch's feature batch and `batches` every batch
+        handed to the launch."""
+        m = self.metrics
+        m.edges_staged += sum(c[0] for c in counts)
+        m.feat_rows_staged += sum(c[1] for c in counts)
+        m.feat_slots_uploaded += int(fb.valid.size)
+        m.upload_bytes += sum(int(x.nbytes) for x in jax.tree.leaves(batches))
 
     def chunk_stream(self, edges, feats, tick_edges: int,
                      feat_with_first_edge: bool = True, seen=None):
@@ -1409,17 +1442,18 @@ class D3Pipeline:
         persistent `seen` set so features still fire exactly once."""
         seen = set() if seen is None else seen
         e_chunks, f_chunks = [], []
-        for lo in range(0, len(edges), tick_edges):
-            chunk = edges[lo: lo + tick_edges]
-            f_events = []
-            if feat_with_first_edge:
-                for u in chunk.reshape(-1):
-                    u = int(u)
-                    if u not in seen and u in feats:
-                        seen.add(u)
-                        f_events.append((u, feats[u]))
-            e_chunks.append(chunk)
-            f_chunks.append(f_events)
+        with self.span("d3.chunk"):
+            for lo in range(0, len(edges), tick_edges):
+                chunk = edges[lo: lo + tick_edges]
+                f_events = []
+                if feat_with_first_edge:
+                    for u in chunk.reshape(-1):
+                        u = int(u)
+                        if u not in seen and u in feats:
+                            seen.add(u)
+                            f_events.append((u, feats[u]))
+                e_chunks.append(chunk)
+                f_chunks.append(f_events)
         return e_chunks, f_chunks
 
     # ------------------------------------------------------ super-tick path
@@ -1433,21 +1467,24 @@ class D3Pipeline:
         query issue ticks are stamped with the tick the scan will admit
         them in.
         """
-        ebs, rbs, vbs, fbs, qbs, lbs = [], [], [], [], [], []
-        for i, (edges_t, feats_t, queries_t, labels_t) in enumerate(
-                zip(edge_chunks, feat_chunks, query_chunks, label_chunks)):
-            eb, rb, vb, fb, qb, lb = self._build_batches(
-                edges_t, feats_t, device=False, queries=queries_t,
-                issue_tick=self.now + i, labels=labels_t)
-            ebs.append(eb)
-            rbs.append(rb)
-            vbs.append(vb)
-            fbs.append(fb)
-            qbs.append(qb)
-            lbs.append(lb)
-        return (ev.stack_batches(fbs), ev.stack_batches(ebs),
-                ev.stack_batches(rbs), ev.stack_batches(vbs),
-                ev.stack_batches(qbs), ev.stack_batches(lbs))
+        with self.span("d3.stage"):
+            ebs, rbs, vbs, fbs, qbs, lbs = [], [], [], [], [], []
+            for i, (edges_t, feats_t, queries_t, labels_t) in enumerate(
+                    zip(edge_chunks, feat_chunks, query_chunks,
+                        label_chunks)):
+                eb, rb, vb, fb, qb, lb = self._build_batches(
+                    edges_t, feats_t, device=False, queries=queries_t,
+                    issue_tick=self.now + i, labels=labels_t)
+                ebs.append(eb)
+                rbs.append(rb)
+                vbs.append(vb)
+                fbs.append(fb)
+                qbs.append(qb)
+                lbs.append(lb)
+            with self.span("d3.stage.pack"):
+                return (ev.stack_batches(fbs), ev.stack_batches(ebs),
+                        ev.stack_batches(rbs), ev.stack_batches(vbs),
+                        ev.stack_batches(qbs), ev.stack_batches(lbs))
 
     def run_super_tick(self, edge_chunks=None, feat_chunks=None,
                        T: Optional[int] = None, window=None,
@@ -1470,7 +1507,6 @@ class D3Pipeline:
         progress stays device-resident until `train_stats()` is read).
         """
         cfg = self.cfg
-        t0 = time.perf_counter()
         outbox_cap = cfg.capacities().outbox
         edge_chunks = list(edge_chunks) if edge_chunks is not None else []
         feat_chunks = list(feat_chunks) if feat_chunks is not None else []
@@ -1484,86 +1520,99 @@ class D3Pipeline:
         feat_chunks += [None] * (T - len(feat_chunks))
         query_chunks += [None] * (T - len(query_chunks))
         label_chunks += [None] * (T - len(label_chunks))
-        batches = self._stage_super_batches(edge_chunks, feat_chunks,
-                                            query_chunks, label_chunks)
-        host_s = time.perf_counter() - t0
-        tick0 = self.now
         counts = [(len(e) if e is not None else 0,
                    len(f) if f else 0, len(q) if q else 0,
                    len(l) if l else 0)
                   for e, f, q, l in zip(edge_chunks, feat_chunks,
                                         query_chunks, label_chunks)]
+        tick0 = self.now
+        self.metrics.launches += 1
+        with self.span("d3.launch", step=True) as launch:
+            s0 = self._clock.total("d3.stage")
+            batches = self._stage_super_batches(edge_chunks, feat_chunks,
+                                                query_chunks, label_chunks)
+            host_s = self._clock.total("d3.stage") - s0
+            self._count_staged(counts, batches[0], batches)
 
-        if self.n_stages > 1:
+            if self.n_stages > 1:
+                carry = st.PipelineCarry(
+                    topo=self.topo, layers=tuple(self.states),
+                    sink=self.sink, sink_seen=self.sink_seen,
+                    queries=self.queries,
+                    now=jnp.asarray(self.now, jnp.int32),
+                    quiet=jnp.asarray(quiet0, jnp.int32),
+                    stage_ring=self.stage_ring, train=self.train_state)
+                with self.span("d3.dispatch"):
+                    (final, stats_sum, idle_sum, qstats_sum, answers,
+                     occ_t) = _super_tick_scan_2d(
+                        self.rounds, self._staged_params(), carry, batches,
+                        window or cfg.window, outbox_cap, self.router,
+                        self.delivery, self.mesh, cfg.delta_eps,
+                        self.train_cfg, self._head, self._acts,
+                        cfg.telemetry)
+                self.topo = final.topo
+                self.states = list(final.layers)
+                self.sink = final.sink
+                self.sink_seen = final.sink_seen
+                self.queries = final.queries
+                self.stage_ring = final.stage_ring
+                self.train_state = final.train
+                self._sync_params_from_train()
+                self.now += T
+                with self.span("d3.sync"):
+                    (host_stats, quiet, host_idle, host_qstats,
+                     host_answers, host_occ) = jax.device_get(
+                        (stats_sum, final.quiet, idle_sum, qstats_sum,
+                         answers, occ_t))
+                with self.span("d3.harvest"):
+                    self._harvest_answers(host_answers)
+                    per_layer = self._unstack_stats(host_stats)
+                    self.metrics.stage_idle += int(np.sum(host_idle))
+                    occ_np = (np.asarray(host_occ)
+                              if self.trace is not None else None)
+                    self._accumulate(per_layer, ticks=T, qstats=host_qstats,
+                                     occ_rows=occ_np)
+                    self._trace_ticks(occ_np, tick0, launch.elapsed(),
+                                      host_s, counts, per_layer, ticks=T,
+                                      amortized=1)
+                return per_layer, int(quiet)
+
             carry = st.PipelineCarry(
                 topo=self.topo, layers=tuple(self.states), sink=self.sink,
                 sink_seen=self.sink_seen, queries=self.queries,
                 now=jnp.asarray(self.now, jnp.int32),
-                quiet=jnp.asarray(quiet0, jnp.int32),
-                stage_ring=self.stage_ring, train=self.train_state)
-            (final, stats_sum, idle_sum, qstats_sum, answers,
-             occ_t) = _super_tick_scan_2d(
-                self.rounds, self._staged_params(), carry, batches,
-                window or cfg.window, outbox_cap, self.router,
-                self.delivery, self.mesh, cfg.delta_eps, self.train_cfg,
-                self._head, self._acts, cfg.telemetry)
+                quiet=jnp.asarray(quiet0, jnp.int32), train=self.train_state)
+            with self.span("d3.dispatch"):
+                final, stats_sum, qstats_sum, answers, occ_t = \
+                    _super_tick_scan(
+                        tuple(self.layers), self.params, carry, batches,
+                        window or cfg.window, outbox_cap, self.router,
+                        self.delivery, self.mesh, cfg.delta_eps,
+                        self.train_cfg, self._head, cfg.telemetry)
             self.topo = final.topo
             self.states = list(final.layers)
             self.sink = final.sink
             self.sink_seen = final.sink_seen
             self.queries = final.queries
-            self.stage_ring = final.stage_ring
             self.train_state = final.train
             self._sync_params_from_train()
             self.now += T
-            (host_stats, quiet, host_idle, host_qstats, host_answers,
-             host_occ) = jax.device_get(
-                (stats_sum, final.quiet, idle_sum, qstats_sum, answers,
-                 occ_t))
-            self._harvest_answers(host_answers)
-            per_layer = self._unstack_stats(host_stats)
-            self.metrics.stage_idle += int(np.sum(host_idle))
-            dt = time.perf_counter() - t0
-            occ_np = (np.asarray(host_occ)
-                      if self.trace is not None else None)
-            self._accumulate(per_layer, dt, ticks=T, qstats=host_qstats,
-                             occ_rows=occ_np)
-            self._trace_ticks(occ_np, tick0, dt, host_s, counts,
-                              per_layer, ticks=T, amortized=1)
-            return per_layer, int(quiet)
-
-        carry = st.PipelineCarry(
-            topo=self.topo, layers=tuple(self.states), sink=self.sink,
-            sink_seen=self.sink_seen, queries=self.queries,
-            now=jnp.asarray(self.now, jnp.int32),
-            quiet=jnp.asarray(quiet0, jnp.int32), train=self.train_state)
-        final, stats_sum, qstats_sum, answers, occ_t = _super_tick_scan(
-            tuple(self.layers), self.params, carry, batches,
-            window or cfg.window, outbox_cap, self.router, self.delivery,
-            self.mesh, cfg.delta_eps, self.train_cfg, self._head,
-            cfg.telemetry)
-        self.topo = final.topo
-        self.states = list(final.layers)
-        self.sink = final.sink
-        self.sink_seen = final.sink_seen
-        self.queries = final.queries
-        self.train_state = final.train
-        self._sync_params_from_train()
-        self.now += T
-        # the one host sync per super-tick: summed stats + quiet counter +
-        # query stats + the T ticks' stacked answers + the telemetry
-        # occupancy rows, in ONE device_get
-        (host_stats, quiet, host_qstats, host_answers,
-         host_occ) = jax.device_get(
-            (stats_sum, final.quiet, qstats_sum, answers, occ_t))
-        self._harvest_answers(host_answers)
-        dt = time.perf_counter() - t0
-        occ_np = np.asarray(host_occ) if self.trace is not None else None
-        self._accumulate(host_stats, dt, ticks=T, qstats=host_qstats,
-                         occ_rows=occ_np)
-        self._trace_ticks(occ_np, tick0, dt, host_s, counts, host_stats,
-                          ticks=T, amortized=1)
-        return host_stats, int(quiet)
+            # the one host sync per super-tick: summed stats + quiet
+            # counter + query stats + the T ticks' stacked answers + the
+            # telemetry occupancy rows, in ONE device_get
+            with self.span("d3.sync"):
+                (host_stats, quiet, host_qstats, host_answers,
+                 host_occ) = jax.device_get(
+                    (stats_sum, final.quiet, qstats_sum, answers, occ_t))
+            with self.span("d3.harvest"):
+                self._harvest_answers(host_answers)
+                occ_np = (np.asarray(host_occ) if self.trace is not None
+                          else None)
+                self._accumulate(host_stats, ticks=T, qstats=host_qstats,
+                                 occ_rows=occ_np)
+                self._trace_ticks(occ_np, tick0, launch.elapsed(), host_s,
+                                  counts, host_stats, ticks=T, amortized=1)
+            return host_stats, int(quiet)
 
     def run_stream_super(self, edges: np.ndarray, feats: dict,
                          tick_edges: int = 256, super_ticks: int = 16,
@@ -1592,13 +1641,15 @@ class D3Pipeline:
         term = TerminationCoordinator()
         override = win.WindowConfig(kind=win.STREAMING) if drain else None
         ran = 0
-        while ran < max_ticks:
-            step = min(T, max_ticks - ran)
-            _, quiet = self.run_super_tick(T=step, window=override,
-                                           quiet0=term.seed_quiet())
-            ran += step
-            if term.observe_flag(quiet):
-                return ran
+        with self.span("d3.drain"):
+            while ran < max_ticks:
+                step = min(T, max_ticks - ran)
+                self.metrics.drain_launches += 1
+                _, quiet = self.run_super_tick(T=step, window=override,
+                                               quiet0=term.seed_quiet())
+                ran += step
+                if term.observe_flag(quiet):
+                    return ran
         raise RuntimeError("pipeline failed to terminate "
                            f"within {max_ticks} flush ticks")
 
@@ -1624,13 +1675,15 @@ class D3Pipeline:
         drain=False waits for the scheduled timers (pure §5.3 behaviour)."""
         term = TerminationCoordinator()
         override = win.WindowConfig(kind=win.STREAMING) if drain else None
-        for i in range(max_ticks):
-            stats = self.tick(window=override)
-            # in-flight inter-stage rows are pending work the host cannot
-            # see in the layer states (0 on a 1-D mesh)
-            if term.observe(self.states, stats, queries=self.queries,
-                            extra_work=self._ring_occupancy_host()):
-                return i + 1
+        with self.span("d3.drain"):
+            for i in range(max_ticks):
+                self.metrics.drain_launches += 1
+                stats = self.tick(window=override)
+                # in-flight inter-stage rows are pending work the host
+                # cannot see in the layer states (0 on a 1-D mesh)
+                if term.observe(self.states, stats, queries=self.queries,
+                                extra_work=self._ring_occupancy_host()):
+                    return i + 1
         raise RuntimeError("pipeline failed to terminate "
                            f"within {max_ticks} flush ticks")
 
@@ -1759,16 +1812,19 @@ def _tick_program(layers, params, topo, states, sink, sink_seen, queries,
     shard_map body under the MeshRouter — the two drivers, the two
     routers and the two delivery backends all share this program."""
     part0 = router.part0()
-    topo = st.apply_vertex_batch(topo, vb, part0)
-    topo = st.apply_repl_batch(topo, rb, part0)
-    topo = st.apply_edge_batch(topo, eb, part0)
-    # does this tick ingest anything that could move state? (replicated
-    # batches — every device votes identically); consistent link heads
-    # only fire when the whole tick is provably still (serve/query.py)
-    batch_work = (jnp.any(inbox.valid) | jnp.any(eb.valid)
-                  | jnp.any(rb.valid))
-    queries, wire, adm_drop, n_adm = query_admit_stage(
-        queries, qb, states, sink, sink_seen, router, batch_work)
+    with jax.named_scope("d3.topo"):
+        topo = st.apply_vertex_batch(topo, vb, part0)
+        topo = st.apply_repl_batch(topo, rb, part0)
+        topo = st.apply_edge_batch(topo, eb, part0)
+    with jax.named_scope("d3.query"):
+        # does this tick ingest anything that could move state?
+        # (replicated batches — every device votes identically);
+        # consistent link heads only fire when the whole tick is provably
+        # still (serve/query.py)
+        batch_work = (jnp.any(inbox.valid) | jnp.any(eb.valid)
+                      | jnp.any(rb.valid))
+        queries, wire, adm_drop, n_adm = query_admit_stage(
+            queries, qb, states, sink, sink_seen, router, batch_work)
     wire_d = None
     new_states, stats_all = [], []
     for li, layer in enumerate(layers):
@@ -1778,10 +1834,11 @@ def _tick_program(layers, params, topo, states, sink, sink_seen, queries,
         lp = ts.params[f"l{li}"] if tcfg is not None else params[f"l{li}"]
         extra = ((wire, (queries.wire_defer, queries.wire_defer_ok))
                  if li == 0 and wire is not None else None)
-        ls, outbox, stats, extra_out = layer_tick_body(
-            layer, lp, topo, states[li], inbox, eb, rb,
-            now, wconf, outbox_cap, router, delivery, extra_lane=extra,
-            delta_eps=delta_eps, telemetry=telemetry)
+        with jax.named_scope(f"d3.layer{li}"):
+            ls, outbox, stats, extra_out = layer_tick_body(
+                layer, lp, topo, states[li], inbox, eb, rb,
+                now, wconf, outbox_cap, router, delivery, extra_lane=extra,
+                delta_eps=delta_eps, telemetry=telemetry)
         if extra is not None:
             wire_d, (wdb, wdo) = extra_out
             queries = replace(queries, wire_defer=wdb, wire_defer_ok=wdo)
@@ -1789,11 +1846,13 @@ def _tick_program(layers, params, topo, states, sink, sink_seen, queries,
         stats_all.append(stats)
         inbox = outbox
     # sink: final-layer emissions materialize the embedding table
-    sink, sink_seen = _sink_update_body(sink, sink_seen, inbox, part0)
+    with jax.named_scope("d3.sink"):
+        sink, sink_seen = _sink_update_body(sink, sink_seen, inbox, part0)
     # query plane: answer point queries from the fresh sink
-    queries, ans, qstats = query_answer_stage(
-        queries, wire_d, qb, adm_drop, n_adm, tuple(new_states), sink,
-        sink_seen, now, stats_all, router)
+    with jax.named_scope("d3.query"):
+        queries, ans, qstats = query_answer_stage(
+            queries, wire_d, qb, adm_drop, n_adm, tuple(new_states), sink,
+            sink_seen, now, stats_all, router)
     # training plane: one windowed online step through the live state
     new_ts = ts
     if tcfg is not None:
@@ -1804,9 +1863,10 @@ def _tick_program(layers, params, topo, states, sink, sink_seen, queries,
         layer_feats = tuple(
             (new_states[li].feat, new_states[li].agg, new_states[li].agg_cnt)
             for li in range(len(layers)))
-        new_ts = train_stage(tcfg, head, layers_bw, layer_feats, topo,
-                             sink, sink_seen, ts, lb, inbox, now, moved,
-                             router, part0)
+        with jax.named_scope("d3.train"):
+            new_ts = train_stage(tcfg, head, layers_bw, layer_feats, topo,
+                                 sink, sink_seen, ts, lb, inbox, now, moved,
+                                 router, part0)
     # telemetry plane: the per-tick occupancy row (trace.py column order)
     occ = (_occ_row(stats_all, qstats, new_ts, router) if telemetry
            else _zero_occ_row())
@@ -1881,8 +1941,9 @@ def _super_tick_scan(layers, params, carry: st.PipelineCarry, batches,
                 c.queries, fb, eb, rb, vb, qb, lb, c.now, wconf,
                 outbox_cap, router, delivery, delta_eps, c.train, tcfg,
                 head, telemetry)
-            quiet = quiet_update(c.quiet, new_layers, stats_t, router,
-                                 queries=queries)
+            with jax.named_scope("d3.quiet"):
+                quiet = quiet_update(c.quiet, new_layers, stats_t, router,
+                                     queries=queries)
             new_c = st.PipelineCarry(
                 topo=topo, layers=new_layers, sink=sink,
                 sink_seen=sink_seen, queries=queries,
@@ -1994,9 +2055,10 @@ def _tick_program_2d(rounds, params, topo, states, sink, sink_seen,
     """
     R = len(rounds)
     part0 = router.part0()
-    topo = st.apply_vertex_batch(topo, vb, part0)
-    topo = st.apply_repl_batch(topo, rb, part0)
-    topo = st.apply_edge_batch(topo, eb, part0)
+    with jax.named_scope("d3.topo"):
+        topo = st.apply_vertex_batch(topo, vb, part0)
+        topo = st.apply_repl_batch(topo, rb, part0)
+        topo = st.apply_edge_batch(topo, eb, part0)
     batch_work = (jnp.any(inbox.valid) | jnp.any(eb.valid)
                   | jnp.any(rb.valid))
     ring = ring[0]                            # local [R, C_buf, W_fb]
@@ -2007,9 +2069,10 @@ def _tick_program_2d(rounds, params, topo, states, sink, sink_seen,
     sq = lambda t: jax.tree.map(lambda a: a[0], t)
     ex = lambda t: jax.tree.map(lambda a: a[None], t)
     sq_states = [sq(s) for s in states]
-    queries, wire, adm_drop, n_adm = query_admit_stage(
-        queries, qb, sq_states, sink, sink_seen, router, batch_work,
-        extra_work=occ0)
+    with jax.named_scope("d3.query"):
+        queries, wire, adm_drop, n_adm = query_admit_stage(
+            queries, qb, sq_states, sink, sink_seen, router, batch_work,
+            extra_work=occ0)
     host_rows = pad_lane(pack_lane(inbox), ring.shape[1])
     is0 = router.stage_index() == 0
     wire_d = None
@@ -2039,11 +2102,13 @@ def _tick_program_2d(rounds, params, topo, states, sink, sink_seen,
                                 jnp.int32(r) * S + sidx)}
         else:
             rparams = sq(params[f"r{r}"])
-        ls, outbox, stats, extra_out = layer_tick_body(
-            rounds[r], rparams, topo, sq_states[r],
-            round_inbox, eb, rb, now, wconf, outbox_cap, router,
-            delivery, extra_lane=extra, delta_eps=delta_eps,
-            telemetry=telemetry)
+        # round r runs layer r * S + stage on each stage
+        with jax.named_scope(f"d3.layer{r}"):
+            ls, outbox, stats, extra_out = layer_tick_body(
+                rounds[r], rparams, topo, sq_states[r],
+                round_inbox, eb, rb, now, wconf, outbox_cap, router,
+                delivery, extra_lane=extra, delta_eps=delta_eps,
+                telemetry=telemetry)
         if extra is not None:
             wire_d, (wdb, wdo) = extra_out
             queries = replace(queries, wire_defer=wdb, wire_defer_ok=wdo)
@@ -2055,8 +2120,10 @@ def _tick_program_2d(rounds, params, topo, states, sink, sink_seen,
         new_slots.append(router.stage_shift(out_rows))
     # same-tick sink feed: the LAST stage's final-round outbox, delivered
     # to every stage's replica of the sink
-    final_fb = unpack_lane(router.stage_last(out_rows), proto)
-    sink, sink_seen = _sink_update_body(sink, sink_seen, final_fb, part0)
+    with jax.named_scope("d3.sink"):
+        final_fb = unpack_lane(router.stage_last(out_rows), proto)
+        sink, sink_seen = _sink_update_body(sink, sink_seen, final_fb,
+                                            part0)
     # the wrap copy stage 0 received in slot R-1 is the final layer's
     # outbox again (already materialized above) — never a round input
     last = new_slots[R - 1]
@@ -2064,9 +2131,10 @@ def _tick_program_2d(rounds, params, topo, states, sink, sink_seen,
     new_slots[R - 1] = last
     new_ring = jnp.stack(new_slots)[None]     # back to [1, R, C_buf, W]
     occ1 = jnp.sum((new_ring[0, ..., vcol] > 0.5).astype(jnp.int32))
-    queries, ans, qstats = query_answer_stage(
-        queries, wire_d, qb, adm_drop, n_adm, tuple(new_states), sink,
-        sink_seen, now, stats_all, router, extra_work=occ1)
+    with jax.named_scope("d3.query"):
+        queries, ans, qstats = query_answer_stage(
+            queries, wire_d, qb, adm_drop, n_adm, tuple(new_states), sink,
+            sink_seen, now, stats_all, router, extra_work=occ1)
     # training plane: every stage gathers ALL rounds' caches over the
     # stage axis and runs the identical full-L backward (TrainState stays
     # stage-replicated; see module docstring of core/train_plane.py)
@@ -2088,9 +2156,10 @@ def _tick_program_2d(rounds, params, topo, states, sink, sink_seen,
             (rounds[0], {"p": ts.params[f"l{l}"],
                          "act": jnp.asarray(acts[l], jnp.float32)}, True)
             for l in range(L))
-        new_ts = train_stage(tcfg, head, layers_bw, tuple(feats_all),
-                             topo, sink, sink_seen, ts, lb, final_fb,
-                             now, moved, router, part0)
+        with jax.named_scope("d3.train"):
+            new_ts = train_stage(tcfg, head, layers_bw, tuple(feats_all),
+                                 topo, sink, sink_seen, ts, lb, final_fb,
+                                 now, moved, router, part0)
     idle_v = router.psum(jnp.stack(idle))[None]   # [1, R] -> [S, R]
     # telemetry plane: the occ row folds the per-stage partial stats over
     # the stage axis (psum_stage / pmax_stage) so it is globally
@@ -2168,10 +2237,11 @@ def _super_tick_scan_2d(rounds, params, carry: st.PipelineCarry, batches,
                 tcfg, head, acts, telemetry)
             # rows still in flight between stages are pending work; the
             # valid flag packs LAST in a FeatBatch wire row
-            occ = jnp.sum((ring[0, ..., -1] > 0.5).astype(jnp.int32))
-            quiet = quiet_update(c.quiet, [sq(s) for s in new_layers],
-                                 [sq(s) for s in stats_t], router,
-                                 queries=queries, extra_work=occ)
+            with jax.named_scope("d3.quiet"):
+                occ = jnp.sum((ring[0, ..., -1] > 0.5).astype(jnp.int32))
+                quiet = quiet_update(c.quiet, [sq(s) for s in new_layers],
+                                     [sq(s) for s in stats_t], router,
+                                     queries=queries, extra_work=occ)
             new_c = st.PipelineCarry(
                 topo=topo, layers=new_layers, sink=sink,
                 sink_seen=sink_seen, queries=queries,

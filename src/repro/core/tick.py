@@ -493,47 +493,56 @@ def layer_tick_body(layer, params, topo: TopoState, ls: LayerState,
         else jnp.zeros((P_loc * N,), jnp.float32)
 
     # ---- Round A: apply inbox at masters, emit + route the broadcast
-    (feat_flat, changed, has_feat, bcast, busy,
-     n_bcast, bcast_cross) = round_a_apply(topo, ls, inbox, new_repl, part0,
-                                           delivery)
-    (bcast_d,), (bc_defer,), rcpt = router.route_lanes(
-        (bcast,), ((ls.bc_defer, ls.bc_defer_ok),))
+    with jax.named_scope("d3.round_a"):
+        (feat_flat, changed, has_feat, bcast, busy,
+         n_bcast, bcast_cross) = round_a_apply(topo, ls, inbox, new_repl,
+                                               part0, delivery)
+    with jax.named_scope("d3.route"):
+        (bcast_d,), (bc_defer,), rcpt = router.route_lanes(
+            (bcast,), ((ls.bc_defer, ls.bc_defer_ok),))
 
     # ---- Round B: apply broadcast at replicas, emit + route the RMIs
     # (the optional extra lane shares this exchange's single all_to_all)
-    (feat_flat, changed, has_feat, x_sent_flat, has_sent, red_pending,
-     red_deadline, rmis, busy, n_reduce, red_cross, n_supp) = round_b_emit(
-        layer, params, topo, ls, feat_flat, changed, has_feat, bcast_d,
-        new_edges, now, wconf, part0, busy, freq, delivery,
-        delta_eps=delta_eps)
-    if delta_eps > 0.0:
-        # approximate mode only: coalesce same-destination additive RMIs
-        # before the outbox/routing plane (stats above counted pre-coalesce)
-        rmis = coalesce_msg_batch(rmis, N)
+    with jax.named_scope("d3.round_b"):
+        (feat_flat, changed, has_feat, x_sent_flat, has_sent, red_pending,
+         red_deadline, rmis, busy, n_reduce, red_cross,
+         n_supp) = round_b_emit(
+            layer, params, topo, ls, feat_flat, changed, has_feat, bcast_d,
+            new_edges, now, wconf, part0, busy, freq, delivery,
+            delta_eps=delta_eps)
+        if delta_eps > 0.0:
+            # approximate mode only: coalesce same-destination additive
+            # RMIs before the outbox/routing plane (stats above counted
+            # pre-coalesce)
+            rmis = coalesce_msg_batch(rmis, N)
     rmi_defer_in = (ls.rmi_defer, ls.rmi_defer_ok)
-    if extra_lane is None:
-        (rmis_d,), (rmi_defer,), rcpt_b = router.route_lanes(
-            (rmis,), (rmi_defer_in,))
-        extra_out = None
-    else:
-        xbatch, xdefer = extra_lane
-        (rmis_d, extra_d), (rmi_defer, xdefer_new), rcpt_b = \
-            router.route_lanes((rmis, xbatch), (rmi_defer_in, xdefer))
-        extra_out = (extra_d, xdefer_new)
-    rcpt = add_receipts(rcpt, rcpt_b)
+    with jax.named_scope("d3.route"):
+        if extra_lane is None:
+            (rmis_d,), (rmi_defer,), rcpt_b = router.route_lanes(
+                (rmis,), (rmi_defer_in,))
+            extra_out = None
+        else:
+            xbatch, xdefer = extra_lane
+            (rmis_d, extra_d), (rmi_defer, xdefer_new), rcpt_b = \
+                router.route_lanes((rmis, xbatch), (rmi_defer_in, xdefer))
+            extra_out = (extra_d, xdefer_new)
+        rcpt = add_receipts(rcpt, rcpt_b)
 
     # ---- apply RMIs at local masters (canonical order first: the additive
     # scatter is the one delivery whose f32 result depends on arrival
     # order, and arrival order is the one thing that depends on D)
-    rmis_d = canon_msg_batch(rmis_d, part0, P_loc, N, router.n_parts)
-    agg_flat, cnt_flat, agg_dirty, busy = apply_rmis(ls, rmis_d, part0,
-                                                     busy, delivery)
+    with jax.named_scope("d3.deliver"):
+        rmis_d = canon_msg_batch(rmis_d, part0, P_loc, N, router.n_parts)
+        agg_flat, cnt_flat, agg_dirty, busy = apply_rmis(
+            ls, rmis_d, part0, busy, delivery)
 
     # ---- forward/update phase (psi), intra-layer window
-    (fwd_pending, fwd_deadline, outbox, busy,
-     n_emit, n_drop, n_demand_pp) = forward_psi(
-        layer, params, topo, ls, feat_flat, has_feat, agg_flat, cnt_flat,
-        agg_dirty, changed, now, wconf, cap_pp, part0, busy, freq, delivery)
+    with jax.named_scope("d3.forward"):
+        (fwd_pending, fwd_deadline, outbox, busy,
+         n_emit, n_drop, n_demand_pp) = forward_psi(
+            layer, params, topo, ls, feat_flat, has_feat, agg_flat,
+            cnt_flat, agg_dirty, changed, now, wconf, cap_pp, part0, busy,
+            freq, delivery)
 
     # ---- adaptive-session CMS update (sketch replicated across devices:
     # local contributions are psum'd so every device applies the same add)

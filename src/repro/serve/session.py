@@ -275,6 +275,10 @@ class ServeSession:
 
     # ------------------------------------------------------------ results
     def _harvest(self):
+        with self.pipe.span("d3.harvest"):
+            self._harvest_answers()
+
+    def _harvest_answers(self):
         cols = self.pipe.drain_answers()
         t_now = time.perf_counter()
         for i in range(len(cols["qid"])):

@@ -8,10 +8,8 @@ bytes, and the tick's ingest counts — into a `TraceRecorder`.
 `save()` writes a compact `.npz` (one int/float column per field plus
 a JSON meta blob: config summary, caps, lane widths, schema version);
 `load_trace()` validates the schema and hands the columns back as
-numpy arrays. The cost model (`telemetry/cost_model.py`) fits per-plane
-cost coefficients from a trace; the capacity advisor
-(`telemetry/advisor.py`) turns the occupancy peaks into recommended
-`Capacities`.
+numpy arrays. The capacity advisor (`telemetry/advisor.py`) turns the
+occupancy peaks into recommended `Capacities`.
 
 Column conventions
 ------------------
@@ -50,11 +48,10 @@ Host columns (`TRACE_HOST_COLS`):
   ticks      : micro-ticks this row covers (1; kept for forward compat)
   wall_s     : wall seconds attributed to the tick (per-tick driver:
                the measured round; scan driver: super-tick wall / T)
-  host_s     : host-side staging seconds (0 on the scan driver — its
-               staging amortizes over the whole super-tick)
-  amortized  : 1 when wall_s is a super-tick average, 0 when measured
-               per tick (the cost model prefers amortized rows: they
-               are far less noisy on CPU)
+  host_s     : host-side staging seconds (the d3.stage span; scan
+               driver: the launch's staging / T)
+  amortized  : 1 when wall_s and host_s are super-tick averages, 0
+               when measured per tick
   wire_bytes : exact bytes on the wire this tick (host-side static
                arithmetic, `D3Pipeline._static_wire_bytes`)
   edges_in / feats_in / queries_in / labels_in : ingest counts

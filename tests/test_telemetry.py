@@ -1,8 +1,7 @@
 """Telemetry plane (ISSUE 9): occupancy-exactness on golden streams
 (every gauge equals the integer count derivable from the plain
-LocalRouter run), trace recorder roundtrip + schema gating, cost-model
-coefficient recovery on synthetic traces, advisor recommendations
-validated by zero-drop replay, and mesh parity at forced-4 (defer-ring
+LocalRouter run), trace recorder roundtrip + schema gating, advisor
+recommendations validated by zero-drop replay, and mesh parity at forced-4 (defer-ring
 gauges vs the `defer_occupancy` oracle, telemetry on == off golden).
 """
 import json
@@ -19,9 +18,8 @@ from repro.core.state import defer_occupancy
 from repro.graph.sage import GraphSAGE
 from repro.telemetry.advisor import (apply_recommendation, recommend,
                                      replay_ok)
-from repro.telemetry.cost_model import CostModel, FEATURES, fit_cost_model
 from repro.telemetry.trace import (TRACE_DEVICE_COLS, TRACE_HOST_COLS,
-                                   Trace, TraceRecorder, load_trace)
+                                   TraceRecorder, load_trace)
 
 N_NODES, D_IN = 32, 8
 
@@ -211,81 +209,6 @@ def test_defer_occupancy_oracle_helper():
              rmi_defer_ok=jnp.array([0, 1, 0, 0], bool))
     b, r = defer_occupancy(ls)
     assert (int(b), int(r)) == (3, 1)
-
-
-# ------------------------------------------------------------ cost model
-
-def _synthetic_trace(T=64, seed=0, c0=2e-3, per_row=None):
-    rng = np.random.default_rng(seed)
-    cols = {c: np.zeros(T, np.int64)
-            for c in TRACE_HOST_COLS + TRACE_DEVICE_COLS}
-    cols["tick"] = np.arange(T)
-    cols["ticks"] = np.ones(T, np.int64)
-    cols["amortized"] = np.ones(T, np.int64)
-    cols["emitted_sum"] = rng.integers(0, 200, T)
-    cols["wire_rows"] = rng.integers(0, 400, T)
-    cols["reduce_msgs"] = rng.integers(0, 300, T)
-    cols["edges_in"] = rng.integers(0, 64, T)
-    per_row = per_row or {"compute_rows": 4e-6, "wire_rows": 1e-6,
-                          "deliver_rows": 2e-6, "ingest_rows": 8e-6}
-    wall = np.full(T, c0)
-    wall += per_row.get("compute_rows", 0) * cols["emitted_sum"]
-    wall += per_row.get("wire_rows", 0) * cols["wire_rows"]
-    wall += per_row.get("deliver_rows", 0) * cols["reduce_msgs"]
-    wall += per_row.get("ingest_rows", 0) * cols["edges_in"]
-    cols["wall_s"] = wall
-    meta = {"schema": 1, "n_parts": 4, "n_devices": 4, "n_stages": 1,
-            "route_cap": None, "wire_lanes": [[100, 13], [160, 13]],
-            "a2a_mult": 64, "fixed_wire_bytes": 1000,
-            "wire_bytes_per_tick": 1000 + 64 * (100 + 160) * 13}
-    cols = {k: np.asarray(v, np.float64 if k in ("wall_s", "host_s")
-                          else np.int64) for k, v in cols.items()}
-    return Trace(meta, cols)
-
-
-def test_cost_model_recovers_synthetic_coefficients():
-    tr = _synthetic_trace()
-    cm = fit_cost_model(tr)
-    assert abs(cm.intercept - 2e-3) < 1e-7
-    for k, want in (("compute_rows", 4e-6), ("wire_rows", 1e-6),
-                    ("deliver_rows", 2e-6), ("ingest_rows", 8e-6)):
-        assert abs(cm.coef[k] - want) < 1e-9, k
-    assert cm.coef["query_rows"] == 0.0 and cm.coef["train_rows"] == 0.0
-    rep = cm.report(tr, tol=0.25)
-    assert rep["n"] == len(tr) and rep["hit_frac"] == 1.0
-    # serialization roundtrip
-    cm2 = CostModel.from_dict(json.loads(json.dumps(cm.to_dict())))
-    np.testing.assert_allclose(cm2.predict(tr.columns),
-                               cm.predict(tr.columns))
-    with pytest.raises(ValueError, match="schema"):
-        CostModel.from_dict({"schema": 0, "intercept": 0, "coef": {}})
-
-
-def test_cost_model_what_if_reprices_wire_exactly():
-    tr = _synthetic_trace()
-    cm = fit_cost_model(tr)
-    # dense (recorded) config reproduces the recorded byte count
-    assert cm.wire_bytes_at() == tr.meta["wire_bytes_per_tick"]
-    # a capped exchange shrinks every lane to route_cap rows
-    assert cm.wire_bytes_at(route_cap=8) == 1000 + 64 * (8 + 8) * 13
-    wi = cm.what_if(tr, route_cap=8)
-    assert wi["wire_bytes_delta"] == (8 + 8 - 100 - 160) * 13 * 64
-    assert wi["wire_delta_s"] < 0 and wi["pred_tick_s"] > 0
-    # doubling the data axis rescales the a2a multiplier (4->8: x4)
-    assert cm.wire_bytes_at(n_devices=8) == \
-        2 * 1000 + 4 * 64 * (100 + 160) * 13
-
-
-def test_cost_model_masks_compile_spikes():
-    tr = _synthetic_trace()
-    tr.columns  # no-op sanity
-    cols = {k: v.copy() for k, v in tr.columns.items()}
-    cols["wall_s"][0] = 50.0          # jit-compile spike
-    spiked = Trace(tr.meta, cols)
-    cm = fit_cost_model(spiked)
-    assert abs(cm.intercept - 2e-3) < 1e-6
-    rep = cm.report(spiked, tol=0.25)
-    assert rep["n"] == len(spiked) - 1 and rep["hit_frac"] == 1.0
 
 
 # --------------------------------------------------------------- advisor
