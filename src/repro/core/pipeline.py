@@ -23,9 +23,14 @@ staged layer ticks + sink update, all over the local part block):
     padded batches (stacked along a leading T axis, one transfer per
     field), then a single jitted `jax.lax.scan` advances all L layers
     through all T ticks with the `PipelineCarry` donated at the jit
-    boundary and exactly ONE host sync per super-tick (the summed stats +
-    quiescence flag read). Same math, same event order — the
-    golden-equivalence tests pin the two drivers to the static oracle.
+    boundary and ONE host sync per super-tick (the summed stats +
+    quiescence flag read). A launch the host need not answer before the
+    next one stays PENDING: its sync comes after the next launch has
+    been staged and dispatched, so the host stages super-tick k+1 while
+    the device runs k (`run_super_tick` says when a launch syncs at
+    once and what settles a pending one). Same math, same event order —
+    the golden-equivalence tests pin the two drivers to the static
+    oracle.
 
 Distributed execution: pass `mesh=` (a 1-D ("data",) mesh, see
 `launch/mesh.py:make_stream_mesh`) and the SAME program runs inside one
@@ -60,7 +65,9 @@ runs the query stage at the end of every tick, AFTER the sink update:
 embedding reads and on-device link scores answered straight from the
 live sharded state, with per-query freshness (`stale_ok` vs
 `consistent`). Answers ride the super-tick scan as its ys — still ONE
-host sync per super-tick (the stats read now also carries the answers).
+host sync per super-tick (the stats read now also carries the answers),
+taken at once by any launch that admits a query or runs while one is
+held, so answers are never left pending.
 Answered rows accumulate host-side; `drain_answers()` pops them
 (`repro/serve/session.py:ServeSession` wraps this with latency
 accounting). `query_cap=0` (default) statically compiles the plane away.
@@ -74,7 +81,8 @@ fire-masked layered backprop + Algorithm 3 update whose two cross-part
 gradient hops ride the same packed wire as the routing plane, and
 `TrainState` (labels, live params, per-part optimizer state,
 error-feedback residuals) lives in the donated carry — still ONE host
-sync per super-tick; `train_stats()` reads progress on demand.
+sync per super-tick, at once for a launch that admits labels;
+`train_stats()` reads progress on demand.
 `train_cap=0` (default) statically compiles the plane away:
 the program is bit-for-bit the four-plane tick.
 `serve/train_session.py:TrainSession` wraps the label queue/driver
@@ -84,7 +92,8 @@ Telemetry plane (ISSUE 9): `PipelineConfig.telemetry=True` turns on the
 SIXTH plane — the one that watches the other five. On device, TickStats
 grows exact occupancy gauges (defer-ring populations, pre-cap route and
 per-part outbox demand peaks) and each tick emits one occupancy row
-that rides the super-tick scan's ys — still ONE host sync. On the host,
+that rides the super-tick scan's ys — still ONE host sync, taken at
+once by every launch while the plane is on. On the host,
 every tick appends a row (device gauges + wall/staging timings + exact
 wire bytes + ingest counts) to `telemetry/trace.py:TraceRecorder`
 (`save_trace()` -> .npz) and feeds `ft/stragglers.py`; the capacity
@@ -457,6 +466,9 @@ class StreamMetrics:
     upload_bytes: int = 0              # nbytes of the batch leaves launched
     launches: int = 0                  # device launches (ticks or scans)
     drain_launches: int = 0            # of which flush launches
+    launches_overlapped: int = 0       # super-tick launches whose sync
+                                       # waited until the next launch was
+                                       # staged and dispatched
 
     @property
     def host_seconds(self) -> float:
@@ -473,6 +485,48 @@ class StreamMetrics:
     @property
     def throughput(self) -> float:
         return self.emitted_total / self.wall_seconds if self.wall_seconds else 0.0
+
+
+@dataclass
+class _Launch:
+    """One dispatched super-tick launch: its host facts, and until the
+    host reads them its device outputs (`D3Pipeline._dispatch_super`)."""
+    ticks: int
+    tick0: int
+    counts: list                       # (edges, feats, queries, labels)
+                                       # per tick
+    span: object = None                # its d3.launch OpenSpan
+    host_s: float = 0.0                # its staging seconds
+    outs: tuple = None                 # unread device outputs
+    result: tuple = None               # (per-layer stats, quiet) once read
+
+
+class LaunchResult:
+    """What `run_super_tick` returns: the pair (per-layer summed
+    TickStats, quiet_ticks). Unpacking or indexing it reads the launch
+    first if it is still pending; a caller that drops it never waits."""
+    __slots__ = ("_pipe", "_launch")
+
+    def __init__(self, pipe, launch: _Launch):
+        self._pipe = pipe
+        self._launch = launch
+
+    def _value(self) -> tuple:
+        if self._launch.result is None:
+            self._pipe.settle()
+        if self._launch.result is None:
+            raise RuntimeError("the launch was never read: reading it "
+                               "raised earlier")
+        return self._launch.result
+
+    def __iter__(self):
+        return iter(self._value())
+
+    def __getitem__(self, i):
+        return self._value()[i]
+
+    def __len__(self) -> int:
+        return 2
 
 
 @dataclass(frozen=True)
@@ -646,9 +700,14 @@ class D3Pipeline:
             self.train_state = jax.device_put(
                 self.train_state, train_shardings(mesh, self.train_state))
         self.now = 0
-        self.metrics = StreamMetrics(
+        self._metrics = StreamMetrics(
             busy_logical=np.zeros(cfg.n_parts, np.int64))
-        self._clock = SpanClock(self.metrics.spans)
+        self._clock = SpanClock(self._metrics.spans)
+        # the one dispatched super-tick whose outputs are not read yet
+        # (`run_super_tick`), and whether the query plane's table held a
+        # query at the end of the last launch the host read
+        self._pending: Optional[_Launch] = None
+        self._queries_held = False
         self._empty_feat = ev.empty_feat_batch(cfg.feat_cap, dims[0])
         empty_rows = {k: np.zeros(0, np.int64) for k in
                       ("part", "edge_slot", "src_slot", "dst_slot",
@@ -691,10 +750,17 @@ class D3Pipeline:
             self.trace = None
             self.straggler = None
 
+    @property
+    def metrics(self) -> StreamMetrics:
+        """The stream's counters, with the pending launch settled first."""
+        self.settle()
+        return self._metrics
+
     def save_trace(self, path) -> None:
         """Write the recorded telemetry trace (needs cfg.telemetry)."""
         assert self.trace is not None, \
             "telemetry plane disabled (PipelineConfig.telemetry=False)"
+        self.settle()
         self.trace.save(path)
 
     def parts_per_shard(self) -> list:
@@ -890,6 +956,7 @@ class D3Pipeline:
         installed config."""
         from repro.ft.elastic import repack_defer_ring, repack_stage_slab
 
+        self.settle()
         L = len(self.layers)
         mesh_shape = dict(new_mesh.shape) if new_mesh is not None else {}
         S = int(mesh_shape.get("stage", 1))
@@ -1303,6 +1370,7 @@ class D3Pipeline:
     def train_stats(self) -> dict:
         """Training-plane progress in ONE host sync: the last fired
         step's global loss, gradient norm and the fired-step count."""
+        self.settle()
         ts = self.train_state
         assert ts is not None, \
             "training plane disabled (train_cap=0 / no TrainConfig)"
@@ -1331,7 +1399,8 @@ class D3Pipeline:
     def drain_answers(self) -> dict:
         """Pop every answered query collected so far as one dict of
         concatenated numpy columns (qid, kind, ok, tick, issue, vec,
-        score) — empty arrays when nothing answered."""
+        score) — empty arrays when nothing answered. A pending launch
+        carries no answers (`run_super_tick`), so nothing is settled."""
         log, self._answer_log = self._answer_log, []
         if not log:
             return {"qid": np.zeros(0, np.int64),
@@ -1353,7 +1422,7 @@ class D3Pipeline:
         occ_rows (telemetry plane): [ticks, len(TRACE_DEVICE_COLS)] int
         per-tick occupancy rows off the device — backlog integrals add,
         the peak gauges fold with max (their scan SUM is meaningless)."""
-        m = self.metrics
+        m = self._metrics
         m.ticks += ticks
         m.wire_bytes += ticks * self._wire_bytes_per_tick
         for s in stats_all:
@@ -1384,6 +1453,11 @@ class D3Pipeline:
             m.queries_answered += int(qstats.answered)
             m.queries_dropped += int(qstats.dropped)
             m.query_hold_ticks += int(qstats.held_ticks)
+            # held_ticks and wire_backlog sum end-of-tick gauges: zero
+            # means the table and the wire were empty when the launch
+            # ended
+            self._queries_held = bool(int(qstats.held_ticks)
+                                      or int(qstats.wire_backlog))
 
     def _trace_ticks(self, occ_rows, tick0, wall_s, host_s, counts,
                      stats_all, ticks: int = 1, amortized: int = 0):
@@ -1420,14 +1494,14 @@ class D3Pipeline:
     def span(self, name: str, step: bool = False):
         """A host span of the current launch (`telemetry/spans.py`),
         totalled into `metrics.spans`."""
-        return self._clock.span(name, self.metrics.launches, step)
+        return self._clock.span(name, self._metrics.launches, step)
 
     def _count_staged(self, counts, fb, batches) -> None:
         """Fold one launch's staged batches into the staging counters:
         `counts` holds a (edges, feats, queries, labels) tuple per tick,
         `fb` is the launch's feature batch and `batches` every batch
         handed to the launch."""
-        m = self.metrics
+        m = self._metrics
         m.edges_staged += sum(c[0] for c in counts)
         m.feat_rows_staged += sum(c[1] for c in counts)
         m.feat_slots_uploaded += int(fb.valid.size)
@@ -1501,13 +1575,28 @@ class D3Pipeline:
         Shorter lists are padded with empty ticks up to T.
         quiet0 seeds the consecutive-quiet-tick counter (flush chaining).
 
-        Returns (per-layer summed TickStats tuple, quiet_ticks) — the ONLY
-        host sync of the super-tick (one device_get that also carries the
-        T ticks' stacked answers and the summed QueryStats; training-plane
-        progress stays device-resident until `train_stats()` is read).
+        Returns a `LaunchResult`: (per-layer summed TickStats tuple,
+        quiet_ticks), read from the launch's one host sync (a device_get
+        that also carries the T ticks' stacked answers and the summed
+        QueryStats; training-plane progress stays device-resident until
+        `train_stats()` is read).
+
+        The launch stages, dispatches, then reads the launch before it
+        if that one is still pending. It syncs at once itself when the
+        host must see its outputs before the next launch: it admits a
+        query or a label, a query admitted earlier was still held when
+        the last read launch ended, the telemetry plane is on (trace
+        rows take the launch's own wall time), or it stages no edge or
+        feature event (a flush launch, read for its quiet counter).
+        Otherwise it stays PENDING: the next launch is staged while the
+        device runs it, and reading the returned value, `metrics`,
+        `settle()`, the next launch, `flush_super`, `flush`, `tick`,
+        `train_stats`, `reshard`, `read_nodes` / `embeddings`,
+        `save_trace` or a checkpoint save reads it. A pending launch
+        carries no answers, so `drain_answers` need not wait for it. An
+        error raised while reading it surfaces at the latest in the next
+        of those calls.
         """
-        cfg = self.cfg
-        outbox_cap = cfg.capacities().outbox
         edge_chunks = list(edge_chunks) if edge_chunks is not None else []
         feat_chunks = list(feat_chunks) if feat_chunks is not None else []
         query_chunks = list(query_chunks) if query_chunks is not None else []
@@ -1525,94 +1614,100 @@ class D3Pipeline:
                    len(l) if l else 0)
                   for e, f, q, l in zip(edge_chunks, feat_chunks,
                                         query_chunks, label_chunks)]
-        tick0 = self.now
-        self.metrics.launches += 1
-        with self.span("d3.launch", step=True) as launch:
+        sync_now = (self.cfg.telemetry or self._queries_held
+                    or any(q or l for _, _, q, l in counts)
+                    or not any(e or f for e, f, _, _ in counts))
+        launch = _Launch(ticks=T, tick0=self.now, counts=counts)
+        self._metrics.launches += 1
+        with self.span("d3.launch", step=True) as span:
+            launch.span = span
             s0 = self._clock.total("d3.stage")
-            batches = self._stage_super_batches(edge_chunks, feat_chunks,
-                                                query_chunks, label_chunks)
-            host_s = self._clock.total("d3.stage") - s0
+            try:
+                batches = self._stage_super_batches(
+                    edge_chunks, feat_chunks, query_chunks, label_chunks)
+            except Exception:
+                self.settle()          # the launch before still folds
+                raise
+            launch.host_s = self._clock.total("d3.stage") - s0
             self._count_staged(counts, batches[0], batches)
+            launch.outs = self._dispatch_super(batches, T, window, quiet0)
+            prev, self._pending = self._pending, launch
+            if prev is not None:
+                self._metrics.launches_overlapped += 1
+                self._fold(prev)
+            if sync_now:
+                self.settle()
+        return LaunchResult(self, launch)
 
+    def _dispatch_super(self, batches, T: int, window, quiet0: int):
+        """Hand the staged launch to the device and install its carry.
+        Returns its unread outputs: (summed stats, quiet counter, summed
+        bubble counters or None on a 1-D mesh, summed QueryStats,
+        stacked answers, occupancy rows)."""
+        cfg = self.cfg
+        carry = st.PipelineCarry(
+            topo=self.topo, layers=tuple(self.states), sink=self.sink,
+            sink_seen=self.sink_seen, queries=self.queries,
+            now=jnp.asarray(self.now, jnp.int32),
+            quiet=jnp.asarray(quiet0, jnp.int32),
+            stage_ring=self.stage_ring, train=self.train_state)
+        wconf = window or cfg.window
+        outbox_cap = cfg.capacities().outbox
+        with self.span("d3.dispatch"):
             if self.n_stages > 1:
-                carry = st.PipelineCarry(
-                    topo=self.topo, layers=tuple(self.states),
-                    sink=self.sink, sink_seen=self.sink_seen,
-                    queries=self.queries,
-                    now=jnp.asarray(self.now, jnp.int32),
-                    quiet=jnp.asarray(quiet0, jnp.int32),
-                    stage_ring=self.stage_ring, train=self.train_state)
-                with self.span("d3.dispatch"):
-                    (final, stats_sum, idle_sum, qstats_sum, answers,
-                     occ_t) = _super_tick_scan_2d(
+                final, stats, idle, qstats, answers, occ = \
+                    _super_tick_scan_2d(
                         self.rounds, self._staged_params(), carry, batches,
-                        window or cfg.window, outbox_cap, self.router,
-                        self.delivery, self.mesh, cfg.delta_eps,
-                        self.train_cfg, self._head, self._acts,
-                        cfg.telemetry)
-                self.topo = final.topo
-                self.states = list(final.layers)
-                self.sink = final.sink
-                self.sink_seen = final.sink_seen
-                self.queries = final.queries
-                self.stage_ring = final.stage_ring
-                self.train_state = final.train
-                self._sync_params_from_train()
-                self.now += T
-                with self.span("d3.sync"):
-                    (host_stats, quiet, host_idle, host_qstats,
-                     host_answers, host_occ) = jax.device_get(
-                        (stats_sum, final.quiet, idle_sum, qstats_sum,
-                         answers, occ_t))
-                with self.span("d3.harvest"):
-                    self._harvest_answers(host_answers)
-                    per_layer = self._unstack_stats(host_stats)
-                    self.metrics.stage_idle += int(np.sum(host_idle))
-                    occ_np = (np.asarray(host_occ)
-                              if self.trace is not None else None)
-                    self._accumulate(per_layer, ticks=T, qstats=host_qstats,
-                                     occ_rows=occ_np)
-                    self._trace_ticks(occ_np, tick0, launch.elapsed(),
-                                      host_s, counts, per_layer, ticks=T,
-                                      amortized=1)
-                return per_layer, int(quiet)
+                        wconf, outbox_cap, self.router, self.delivery,
+                        self.mesh, cfg.delta_eps, self.train_cfg,
+                        self._head, self._acts, cfg.telemetry)
+            else:
+                final, stats, qstats, answers, occ = _super_tick_scan(
+                    tuple(self.layers), self.params, carry, batches, wconf,
+                    outbox_cap, self.router, self.delivery, self.mesh,
+                    cfg.delta_eps, self.train_cfg, self._head,
+                    cfg.telemetry)
+                idle = None
+        self.topo = final.topo
+        self.states = list(final.layers)
+        self.sink = final.sink
+        self.sink_seen = final.sink_seen
+        self.queries = final.queries
+        self.stage_ring = final.stage_ring
+        self.train_state = final.train
+        self._sync_params_from_train()
+        self.now += T
+        # `quiet` is read from this carry; the next launch donates a
+        # fresh one, so the read stays valid after it
+        return stats, final.quiet, idle, qstats, answers, occ
 
-            carry = st.PipelineCarry(
-                topo=self.topo, layers=tuple(self.states), sink=self.sink,
-                sink_seen=self.sink_seen, queries=self.queries,
-                now=jnp.asarray(self.now, jnp.int32),
-                quiet=jnp.asarray(quiet0, jnp.int32), train=self.train_state)
-            with self.span("d3.dispatch"):
-                final, stats_sum, qstats_sum, answers, occ_t = \
-                    _super_tick_scan(
-                        tuple(self.layers), self.params, carry, batches,
-                        window or cfg.window, outbox_cap, self.router,
-                        self.delivery, self.mesh, cfg.delta_eps,
-                        self.train_cfg, self._head, cfg.telemetry)
-            self.topo = final.topo
-            self.states = list(final.layers)
-            self.sink = final.sink
-            self.sink_seen = final.sink_seen
-            self.queries = final.queries
-            self.train_state = final.train
-            self._sync_params_from_train()
-            self.now += T
-            # the one host sync per super-tick: summed stats + quiet
-            # counter + query stats + the T ticks' stacked answers + the
-            # telemetry occupancy rows, in ONE device_get
-            with self.span("d3.sync"):
-                (host_stats, quiet, host_qstats, host_answers,
-                 host_occ) = jax.device_get(
-                    (stats_sum, final.quiet, qstats_sum, answers, occ_t))
-            with self.span("d3.harvest"):
-                self._harvest_answers(host_answers)
-                occ_np = (np.asarray(host_occ) if self.trace is not None
-                          else None)
-                self._accumulate(host_stats, ticks=T, qstats=host_qstats,
-                                 occ_rows=occ_np)
-                self._trace_ticks(occ_np, tick0, launch.elapsed(), host_s,
-                                  counts, host_stats, ticks=T, amortized=1)
-            return host_stats, int(quiet)
+    def settle(self) -> None:
+        """Read the pending super-tick launch, if there is one, and fold
+        it into the host's counters, answers and trace."""
+        launch, self._pending = self._pending, None
+        if launch is not None:
+            self._fold(launch)
+
+    def _fold(self, launch: "_Launch") -> None:
+        """The launch's one host sync (summed stats, quiet counter, query
+        stats, the T ticks' stacked answers and the telemetry occupancy
+        rows in ONE device_get), then its harvest."""
+        with self.span("d3.sync"):
+            stats, quiet, idle, qstats, answers, occ = jax.device_get(
+                launch.outs)
+        launch.outs = None
+        with self.span("d3.harvest"):
+            self._harvest_answers(answers)
+            if idle is not None:
+                stats = self._unstack_stats(stats)
+                self._metrics.stage_idle += int(np.sum(idle))
+            occ_np = np.asarray(occ) if self.trace is not None else None
+            self._accumulate(stats, ticks=launch.ticks, qstats=qstats,
+                             occ_rows=occ_np)
+            self._trace_ticks(occ_np, launch.tick0, launch.span.elapsed(),
+                              launch.host_s, launch.counts, stats,
+                              ticks=launch.ticks, amortized=1)
+        launch.result = (stats, int(quiet))
 
     def run_stream_super(self, edges: np.ndarray, feats: dict,
                          tick_edges: int = 256, super_ticks: int = 16,
@@ -1637,14 +1732,16 @@ class D3Pipeline:
 
         The consecutive-quiet counter lives in the scan carry; the host
         reads it once per super-tick and re-seeds the next launch through
-        the coordinator's public seed_quiet()."""
+        the coordinator's public seed_quiet(). Every flush launch stages
+        no event and so syncs at once; the first one also reads the
+        launch left pending before the flush."""
         term = TerminationCoordinator()
         override = win.WindowConfig(kind=win.STREAMING) if drain else None
         ran = 0
         with self.span("d3.drain"):
             while ran < max_ticks:
                 step = min(T, max_ticks - ran)
-                self.metrics.drain_launches += 1
+                self._metrics.drain_launches += 1
                 _, quiet = self.run_super_tick(T=step, window=override,
                                                quiet0=term.seed_quiet())
                 ran += step
@@ -1698,6 +1795,7 @@ class D3Pipeline:
         plane's stale_ok reads: a stale_ok answer at tick t bit-matches
         `read_nodes` called right after tick t.
         """
+        self.settle()
         vids = np.asarray(list(vids) if not isinstance(vids, np.ndarray)
                           else vids, np.int64).reshape(-1)
         t = self.part.t

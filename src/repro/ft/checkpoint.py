@@ -254,6 +254,7 @@ class CheckpointManager:
         LayerState and held point queries live in the QueryState table,
         so this IS the Chandy-Lamport-equivalent cut — a restored carry
         answers pending `consistent` queries identically."""
+        pipe.settle()
         t = pipe.part.t
         aux = {
             "degree": t.degree, "replicas": t.replicas, "load": t.load,
@@ -279,6 +280,7 @@ class CheckpointManager:
         self.save(step, tree, meta={"now": pipe.now}, aux=aux)
 
     def restore_pipeline(self, pipe, step: int | None = None) -> int:
+        pipe.settle()
         template = {"topo": pipe.topo, "layers": pipe.states,
                     "sink": pipe.sink, "sink_seen": pipe.sink_seen,
                     "queries": pipe.queries, "params": pipe.params,
@@ -312,4 +314,7 @@ class CheckpointManager:
         t.slot_of = {(int(p), int(v)): int(s)
                      for (p, v), s in zip(keys, vals)}
         pipe.now = int(np.asarray(h["now"]))
+        # the restored table may hold queries: launches sync at once
+        # until one reports it empty
+        pipe._queries_held = True
         return got_step

@@ -9,7 +9,9 @@ drives EITHER pipeline driver with queries aboard:
     stages T update micro-ticks and spreads the queued submissions over
     them, so queries admit while updates are still flowing through the
     same device launch. Answers come back in the launch's single host
-    sync.
+    sync, which a launch that admits a query (or runs while one is held)
+    takes at once; a query-free launch leaves its sync pending until the
+    next launch has been staged (`D3Pipeline.run_super_tick`).
 
 The session keeps the host-side truth the device never sees: wall-clock
 enqueue times per qid. Every harvested answer gets an end-to-end
@@ -237,7 +239,14 @@ class ServeSession:
         interleaves with
         the update stream on device. Submissions beyond the launch's
         admission budget stay queued for the next advance — they never
-        overflow a tick's fixed-capacity query batch."""
+        overflow a tick's fixed-capacity query batch.
+
+        A launch that admits queries, or runs while admitted ones are
+        still held, syncs before this returns and its answers are
+        harvested here. A query-free launch returns with its sync
+        pending: the next advance stages while the device runs it, and
+        `flush`, the returned value or the pipeline's `metrics` read it
+        (`D3Pipeline.run_super_tick` lists what else does)."""
         edge_chunks = list(edge_chunks) if edge_chunks is not None else []
         feat_chunks = list(feat_chunks) if feat_chunks is not None else []
         n = max(len(edge_chunks), len(feat_chunks), 1)

@@ -176,6 +176,7 @@ def test_counters_match_the_staged_batches(driver, monkeypatch):
     fb_at = 3 if driver == "tick" else 0
     fbs = [b[fb_at] for b in staged]
     assert m.launches == len(staged) == m.spans["d3.launch"].count
+    assert m.spans["d3.sync"].count == m.launches
     assert 0 < m.drain_launches < m.launches
     assert m.edges_staged == len(edges)
     assert m.feat_rows_staged == sum(int(np.sum(np.asarray(fb.valid)))
