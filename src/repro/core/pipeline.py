@@ -115,7 +115,7 @@ from __future__ import annotations
 
 import warnings
 from dataclasses import dataclass, field, replace
-from functools import partial
+from functools import lru_cache, partial
 from typing import Optional
 
 import jax
@@ -566,6 +566,55 @@ class StagedActLayer:
         return jnp.where(params["act"] > 0, jax.nn.relu(h), h)
 
 
+def _fresh_tables(n_parts, node_cap, edge_cap, repl_cap, dims, n_stages,
+                  n_rounds, bc_rows, rmi_rows, query_cap, query_defer_rows,
+                  ring_rows):
+    """A pipeline's zeroed device tables: (topology, layer states, sink,
+    sink_seen, pending queries, inter-stage ring). On a 2-D mesh
+    (n_stages > 1) each of the n_rounds states stacks its round's layers
+    over a leading stage axis (all layers initialize identically) and the
+    ring is [S, rounds, *ring_rows]; on one stage the ring is None."""
+    topo = st.init_topo(n_parts, edge_cap, repl_cap, node_cap)
+    if n_stages > 1:
+        d = dims[0]
+        proto = st.init_layer(n_parts, node_cap, d, d, bc_defer_rows=bc_rows,
+                              rmi_defer_rows=rmi_rows)
+        states = [jax.tree.map(lambda a: jnp.stack([a] * n_stages), proto)
+                  for _ in range(n_rounds)]
+    else:
+        states = [st.init_layer(n_parts, node_cap, d, d,
+                                bc_defer_rows=bc_rows,
+                                rmi_defer_rows=rmi_rows)
+                  for d in dims[:-1]]
+    queries = init_query_state(n_parts, query_cap, dims[-1],
+                               wire_defer_rows=query_defer_rows)
+    ring = (jnp.zeros((n_stages, n_rounds) + ring_rows, jnp.float32)
+            if n_stages > 1 else None)
+    return (topo, states, jnp.zeros((n_parts, node_cap, dims[-1])),
+            jnp.zeros((n_parts, node_cap), bool), queries, ring)
+
+
+@lru_cache(maxsize=None)
+def _fresh_tables_on(mesh, *sizes):
+    """`_fresh_tables(*sizes)` jitted to build every table in its place on
+    `mesh`: each device zeroes its own shards, so the whole carry never
+    lands on one device on its way onto the mesh. A table with no element
+    (a defer ring of the dense wire) is built replicated, as a launch
+    hands it back (XLA returns an empty output replicated, whatever its
+    spec): the first launch of a pipeline then runs the program every
+    later launch runs. Kept per mesh and sizes, so that a pipeline made
+    again at the same sizes compiles nothing."""
+    build = partial(_fresh_tables, *sizes)
+    n_stages, n_rounds = sizes[5], sizes[6]
+    sh = (stage_carry_shardings(mesh, n_rounds) if n_stages > 1
+          else carry_shardings(mesh, n_rounds))
+    rep = NamedSharding(mesh, P())
+    out = jax.tree.map(lambda s, a: rep if a.size == 0 else s,
+                       (sh.topo, list(sh.layers), sh.sink, sh.sink_seen,
+                        sh.queries, sh.stage_ring), jax.eval_shape(build))
+    return jax.jit(build, out_shardings=out)
+
+
 class D3Pipeline:
     """L chained GraphStorage operators + the host driver."""
 
@@ -647,44 +696,12 @@ class D3Pipeline:
         cap_pp = caps.outbox_per_part
         self._ring_caps = (max(cfg.feat_cap, p_loc * cap_pp), dims[0] + 3)
 
-        def fresh_tables():
-            topo = st.init_topo(cfg.n_parts, cfg.edge_cap, cfg.repl_cap,
-                                cfg.node_cap)
-            if S > 1:
-                d = dims[0]
-                proto = st.init_layer(cfg.n_parts, cfg.node_cap, d, d,
-                                      bc_defer_rows=bc_rows,
-                                      rmi_defer_rows=rmi_rows)
-                # round r's state stacks layers r*S+0 .. r*S+S-1 over a
-                # leading stage axis (all layers initialize identically)
-                states = [jax.tree.map(lambda a: jnp.stack([a] * S), proto)
-                          for _ in range(self._n_rounds)]
-            else:
-                states = [st.init_layer(cfg.n_parts, cfg.node_cap, dims[i],
-                                        dims[i], bc_defer_rows=bc_rows,
-                                        rmi_defer_rows=rmi_rows)
-                          for i in range(len(self.layers))]
-            queries = init_query_state(
-                cfg.n_parts, cfg.query_cap, self.d_out,
-                wire_defer_rows=caps.query_defer_rows)
-            ring = (jnp.zeros((S, self._n_rounds, n_dev * self._ring_caps[0],
-                               self._ring_caps[1]), jnp.float32)
-                    if S > 1 else None)
-            return (topo, states,
-                    jnp.zeros((cfg.n_parts, cfg.node_cap, self.d_out)),
-                    jnp.zeros((cfg.n_parts, cfg.node_cap), bool), queries,
-                    ring)
-
-        if mesh is None:
-            tables = fresh_tables()
-        else:
-            # each device zeroes its own shards: the whole carry never
-            # lands on one device on its way onto the mesh
-            sh = (stage_carry_shardings(mesh, self._n_rounds) if S > 1
-                  else carry_shardings(mesh, len(self.layers)))
-            tables = jax.jit(fresh_tables, out_shardings=(
-                sh.topo, list(sh.layers), sh.sink, sh.sink_seen, sh.queries,
-                sh.stage_ring))()
+        sizes = (cfg.n_parts, cfg.node_cap, cfg.edge_cap, cfg.repl_cap,
+                 tuple(dims), S, self._n_rounds, bc_rows, rmi_rows,
+                 cfg.query_cap, caps.query_defer_rows,
+                 (n_dev * self._ring_caps[0], self._ring_caps[1]))
+        tables = (_fresh_tables(*sizes) if mesh is None
+                  else _fresh_tables_on(mesh, *sizes)())
         (self.topo, self.states, self.sink, self.sink_seen, self.queries,
          self.stage_ring) = tables
         # the training plane's device state: labels/dirty window, live

@@ -83,6 +83,11 @@ the exchange (pre-ISSUE-5 the `jnp.clip(part // Pl, 0, D-1)` silently
 misrouted them to the last device, where they burned bucket capacity
 before being dropped at delivery).
 
+Device planes: the caller's `d3.route` scope covers packing and
+unpacking; the collective alone (the all_to_all, and the stage axis's
+ppermute) runs under `d3.wire`, so a profiler trace tells the exchange
+apart from its packing.
+
 Routers are small frozen dataclasses so they can ride jit boundaries as
 static arguments. `MeshRouter` methods are only valid INSIDE a
 `shard_map` over its axis (they call `lax.axis_index`/`lax.all_to_all`);
@@ -283,8 +288,9 @@ class MeshRouter:
         after each round's compute so the hop is double-buffered behind
         the next round's work."""
         S = self.n_stages
-        return lax.ppermute(rows, self.stage_axis,
-                            [(i, (i + 1) % S) for i in range(S)])
+        with jax.named_scope("d3.wire"):
+            return lax.ppermute(rows, self.stage_axis,
+                                [(i, (i + 1) % S) for i in range(S)])
 
     def stage_last(self, rows):
         """Every stage's copy of the LAST stage's rows (the final GNN
@@ -383,8 +389,9 @@ class MeshRouter:
                 n_drop = n_drop + jnp.sum(left_s.astype(jnp.int32))
 
         buf = jnp.concatenate(sends, axis=1)                   # [D, X]
-        got = lax.all_to_all(buf, self.axis, split_axis=0,
-                             concat_axis=0, tiled=True)        # [D, X]
+        with jax.named_scope("d3.wire"):
+            got = lax.all_to_all(buf, self.axis, split_axis=0,
+                                 concat_axis=0, tiled=True)    # [D, X]
         outs, off = [], 0
         for proto, cap, W in metas:
             blk = got[:, off:off + cap * W].reshape(D * cap, W)

@@ -21,8 +21,9 @@ annotation object, so spans sit around whole layers, once per launch
 or once per micro-tick, never inside a per-edge or per-row loop.
 
 Device planes are named by `jax.named_scope("d3.<plane>")` inside the
-compiled tick (`core/tick.py`, `core/pipeline.py`); scopes are op
-metadata only and leave the compiled arithmetic as it is.
+compiled tick (`core/tick.py`, `core/pipeline.py`, and `d3.wire` round
+the collective in `dist/router.py`); scopes are op metadata only and
+leave the compiled arithmetic as it is.
 """
 from __future__ import annotations
 
