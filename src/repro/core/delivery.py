@@ -15,7 +15,7 @@ A backend provides the three state effects the tick's hot path needs:
                   inbox apply, Round B broadcast apply) — last-writer-
                   wins plus a touched flag per row;
   deliver_add   : aggregator RMI records ADD (delta vec, delta cnt) at
-                  local masters plus a dirty flag (apply_rmis) — one
+                  local masters plus a dirty flag (deliver_rmis) — one
                   delivery regardless of the reduce/replace/remove mix;
   agg_read_rows : the MEAN-synopsis read at the forward stage's picked
                   rows (forward_psi).

@@ -435,6 +435,12 @@ class StreamMetrics:
                                        # the saved message volume:
                                        # reduce_msgs + suppressed tracks
                                        # the ungated reduce_msgs
+    # compacted delivery: lane rows the RMI deliveries covered (whole
+    # deliver_chunk steps, summed over layers and devices) and the rows
+    # the delivered lanes held (their static capacity);
+    # 1 - deliver_rows / deliver_lane_rows is the share compaction saved
+    deliver_rows: int = 0
+    deliver_lane_rows: int = 0
     stage_idle: int = 0                # hybrid pipeline bubbles (ISSUE 7):
                                        # device-rounds that saw an EMPTY
                                        # inbox, summed over ticks — 0 on a
@@ -713,6 +719,7 @@ class D3Pipeline:
         self._acts = tuple(
             1.0 if getattr(l, "act", False) else 0.0 for l in self.layers)
         self._wire_bytes_per_tick = self._static_wire_bytes(dims, n_dev, S)
+        self._lane_rows_per_tick = self._static_lane_rows(n_dev)
         if mesh is not None and self.train_state is not None:
             self.train_state = jax.device_put(
                 self.train_state, train_shardings(mesh, self.train_state))
@@ -859,6 +866,17 @@ class D3Pipeline:
                 n_dev * (p_loc * cfg.repl_cap + p_loc * cfg.node_cap)
                 * (dims[li] + 5) * 4 for li in range(len(self.layers)))
         return total
+
+    def _static_lane_rows(self, n_dev: int) -> int:
+        """Rows of the delivered RMI lanes per tick, over every layer and
+        device (StreamMetrics.deliver_lane_rows): each device receives,
+        per layer, its whole emission lane on one data shard, else one
+        `lane_cap` bucket from each of the n_dev shards."""
+        cfg = self.cfg
+        rows = cfg.edge_tick_cap + cfg.n_parts // n_dev * cfg.edge_cap
+        if self.mesh is not None and n_dev > 1:
+            rows = n_dev * self.router.lane_cap(rows)
+        return len(self.layers) * n_dev * rows
 
     def _check_uniform_layers(self, dims) -> None:
         """Stage parallelism runs ONE compiled round body for every layer
@@ -1079,6 +1097,7 @@ class D3Pipeline:
                        if new_mesh is not None else LocalRouter(cfg.n_parts))
         self._ring_caps = ring_caps
         self._wire_bytes_per_tick = self._static_wire_bytes(dims, n_dev, S)
+        self._lane_rows_per_tick = self._static_lane_rows(n_dev)
         if new_mesh is not None and S > 1:
             sh = stage_carry_shardings(new_mesh, n_rounds)
             self.topo = jax.device_put(self.topo, sh.topo)
@@ -1442,6 +1461,7 @@ class D3Pipeline:
         m = self._metrics
         m.ticks += ticks
         m.wire_bytes += ticks * self._wire_bytes_per_tick
+        m.deliver_lane_rows += ticks * self._lane_rows_per_tick
         for s in stats_all:
             m.reduce_msgs += int(s.reduce_msgs)
             m.broadcast_msgs += int(s.broadcast_msgs)
@@ -1451,6 +1471,7 @@ class D3Pipeline:
             m.route_deferred += int(s.route_deferred)
             m.route_dropped += int(s.route_dropped)
             m.suppressed += int(s.n_suppressed)
+            m.deliver_rows += int(s.deliver_rows)
             m.occ_defer_ticks += int(s.occ_bc_defer) + int(s.occ_rmi_defer)
             m.busy_logical += np.asarray(s.busy, np.int64)
         m.emitted_total += int(stats_all[-1].emitted)

@@ -37,9 +37,11 @@ Router delivery between them:
                   with per-destination buckets capped by route_cap;
                   overflow defers into per-lane rings in LayerState
                   (bc_defer/rmi_defer) and re-enters next tick.
-  apply_rmis    : ONE delivery (delivery.deliver_add) applies any RMI mix
+  deliver_rmis  : ONE delivery (delivery.deliver_add) applies any RMI mix
                   at the local masters — a flat scatter-add on the "xla"
-                  backend, a sorted Pallas segment reduction on "pallas".
+                  backend, a sorted Pallas segment reduction on "pallas" —
+                  over the delivered lane's live rows only, in steps of
+                  `deliver_chunk` rows.
   forward_psi   : dirty masters run the update (psi) under the intra-layer
                   window and emit into a per-part capacity-limited outbox;
                   the aggregator read goes through delivery.agg_read_rows
@@ -117,6 +119,11 @@ class TickStats:
     # eps for a fixed send schedule); psum'd over the mesh; always 0 in
     # exact mode (delta_eps=0 compiles the gate away).
     n_suppressed: jnp.ndarray
+    # compacted delivery: the lane rows the RMI delivery's steps covered
+    # (deliver_chunk rows a step), psum'd over the mesh like wire_rows.
+    # The lane's full capacity is a compile-time constant, accounted
+    # host-side (StreamMetrics.deliver_lane_rows).
+    deliver_rows: jnp.ndarray
     # telemetry plane (ISSUE 9) — occupancy gauges, static zeros unless
     # PipelineConfig.telemetry=True (XLA dead-code-eliminates them, so the
     # default program is bit-for-bit the five-plane tick). The defer-ring
@@ -146,7 +153,7 @@ jax.tree_util.register_dataclass(
                             "cross_part_msgs", "emitted", "dropped",
                             "wire_rows", "route_deferred",
                             "route_dropped", "n_suppressed",
-                            "occ_bc_defer", "occ_rmi_defer",
+                            "deliver_rows", "occ_bc_defer", "occ_rmi_defer",
                             "route_peak", "outbox_part_peak", "busy"],
     meta_fields=[])
 
@@ -160,8 +167,8 @@ def zero_stats(n_parts: int) -> TickStats:
     return TickStats(broadcast_msgs=z, reduce_msgs=z, cross_part_msgs=z,
                      emitted=z, dropped=z, wire_rows=z,
                      route_deferred=z, route_dropped=z, n_suppressed=z,
-                     occ_bc_defer=z, occ_rmi_defer=z, route_peak=z,
-                     outbox_part_peak=z,
+                     deliver_rows=z, occ_bc_defer=z, occ_rmi_defer=z,
+                     route_peak=z, outbox_part_peak=z,
                      busy=jnp.zeros((n_parts,), jnp.int32))
 
 
@@ -335,48 +342,84 @@ def round_b_emit(layer, params, topo: TopoState, ls: LayerState, feat_flat,
             n_supp)
 
 
-def canon_msg_batch(b: MsgBatch, part0, P_loc: int, N: int,
-                    n_parts: int) -> MsgBatch:
-    """Deterministic delivery (ISSUE 10): reorder a DELIVERED additive
-    batch into the canonical (local destination index, source part) order
-    with a stable sort.
-
-    The all_to_all concatenates arrivals by SOURCE DEVICE, so the order
-    in which two records from different shards reach the same aggregator
-    depends on the device count — the one place the mesh program's f32
-    sums depend on D. Rows from the SAME source part always arrive in
-    that part's emission order (route_pack and the defer rings are
-    order-preserving), so a stable sort keyed by
-    (dst_idx * n_parts + src_part) is a TOTAL canonical order: uncapped
-    mesh runs become bit-equal across any device count, which is what
-    lets a live reshard (D -> D') be verified against the uninterrupted
-    run with assert_array_equal rather than allclose. Invalid rows carry
-    the one-past-the-end sentinel index and sort to the back.
-
-    Key fits int32 for any realistic config (P_loc * N * n_parts < 2^31).
-    """
-    idx, _ = local_index(b.part, b.slot, part0, P_loc, N, b.valid)
-    key = idx * jnp.int32(n_parts) + jnp.clip(b.src_part, 0, n_parts - 1)
-    order = jnp.argsort(key, stable=True)
-    return MsgBatch(part=b.part[order], slot=b.slot[order],
-                    vec=b.vec[order], cnt=b.cnt[order],
-                    src_part=b.src_part[order], valid=b.valid[order])
+def deliver_chunk(capacity: int) -> int:
+    """Rows a delivered RMI lane of `capacity` rows is delivered in per
+    step: capacity / 64 rounded up to a multiple of 1,024, at most the
+    whole lane. A function of the lane's shape alone, so every router,
+    driver and backend compiles the same delivery."""
+    k = -(-capacity // 64)
+    return min(capacity, -(-k // 1024) * 1024)
 
 
-def apply_rmis(ls: LayerState, rmis_d: MsgBatch, part0, busy, delivery):
-    """Apply DELIVERED aggregator RMIs at local masters: one delivery
-    regardless of the reduce/replace/remove mix (flat scatter-add on
-    "xla", sorted segment reduction on "pallas").
+def deliver_rmis(ls: LayerState, rmis_d: MsgBatch, part0, busy, delivery,
+                 n_parts: int):
+    """Apply DELIVERED aggregator RMIs at local masters (flat scatter-add
+    on "xla", sorted segment reduction on "pallas"), over the lane's LIVE
+    rows only, whatever the reduce/replace/remove mix.
 
-    Returns (agg_flat, cnt_flat, agg_dirty, busy)."""
+    Canonical order: the all_to_all concatenates arrivals by SOURCE
+    DEVICE, so the order in which two records from different shards reach
+    the same aggregator depends on the device count — the one place the
+    mesh program's f32 sums depend on D. Rows from the SAME source part
+    always arrive in that part's emission order (route_pack and the defer
+    rings are order-preserving), so (source part, arrival) is a TOTAL
+    canonical order, and every aggregator receives its records in it:
+    uncapped mesh runs are bit-equal across any device count, which lets
+    a live reshard (D -> D') be verified against the uninterrupted run
+    with assert_array_equal rather than allclose.
+
+    Compaction: a delivered lane is mostly padding (the dense wire hands
+    each device D x the emission capacity a tick; on one device the lane
+    is the whole emission capacity). A cumsum per source part over the
+    lane's live flags gives each live row its canonical slot, and one
+    scatter lists the live rows in that order. The delivery then runs in
+    steps of `deliver_chunk(C)` rows over as many steps as the live rows
+    need (none on a quiet tick), so every load is delivered exactly:
+    nothing is capped, dropped or deferred. A scatter-add applies records
+    in order, so on "xla" the steps give the single delivery's sums bit
+    for bit; "pallas" sums each step's records before adding them, so
+    past one step its sums round differently (as they differ from
+    "xla"'s).
+
+    Returns (agg_flat, cnt_flat, agg_dirty, busy, n_rows) — n_rows is the
+    lane rows the delivery's steps covered."""
     P_loc, N, d_agg = ls.agg.shape
+    C = rmis_d.capacity
+    G = deliver_chunk(C)
     idx, lp = local_index(rmis_d.part, rmis_d.slot, part0, P_loc, N,
                           rmis_d.valid)
-    agg_flat, cnt_flat, agg_dirty = delivery.deliver_add(
-        ls.agg.reshape(P_loc * N, d_agg), ls.agg_cnt.reshape(P_loc * N),
-        idx, rmis_d.vec, rmis_d.cnt)
-    busy = busy.at[lp].add(1, mode="drop")
-    return agg_flat, cnt_flat, agg_dirty, busy
+    live = lp < P_loc
+    src = jnp.clip(rmis_d.src_part, 0, n_parts - 1)
+    # canonical slot of each live row: the live rows of every lower source
+    # part first, then its rank among its own part's live rows
+    of_part = src[None, :] == jnp.arange(n_parts, dtype=src.dtype)[:, None]
+    rank = jnp.cumsum((live[None, :] & of_part).astype(jnp.int32), axis=1)
+    per_part = rank[:, -1]
+    first = jnp.cumsum(per_part) - per_part
+    pos = first[src] + jnp.sum(jnp.where(of_part, rank, 0), axis=0) - 1
+    n_chunks = -(-C // G)
+    rows = jnp.full((n_chunks * G,), C, jnp.int32).at[
+        jnp.where(live, pos, n_chunks * G)].set(
+            jnp.arange(C, dtype=jnp.int32), mode="drop")
+
+    def chunk(i, carry):
+        agg, cnt, dirty = carry
+        r = jax.lax.dynamic_slice(rows, (i * G,), (G,))
+        pad = r == C
+        r = jnp.minimum(r, C - 1)
+        agg, cnt, d = delivery.deliver_add(
+            agg, cnt, jnp.where(pad, P_loc * N, idx[r]), rmis_d.vec[r],
+            rmis_d.cnt[r])
+        return agg, cnt, dirty | d
+
+    took = (jnp.sum(per_part) + G - 1) // G
+    agg, cnt, dirty = jax.lax.fori_loop(
+        0, took, chunk, (ls.agg.reshape(P_loc * N, d_agg),
+                         ls.agg_cnt.reshape(P_loc * N),
+                         jnp.zeros((P_loc * N,), bool)))
+    busy = busy + jnp.sum(lp[None, :] == jnp.arange(P_loc)[:, None], axis=1,
+                          dtype=jnp.int32)
+    return agg, cnt, dirty, busy, jnp.minimum(took * G, C)
 
 
 def forward_psi(layer, params, topo: TopoState, ls: LayerState, feat_flat,
@@ -528,13 +571,12 @@ def layer_tick_body(layer, params, topo: TopoState, ls: LayerState,
             extra_out = (extra_d, xdefer_new)
         rcpt = add_receipts(rcpt, rcpt_b)
 
-    # ---- apply RMIs at local masters (canonical order first: the additive
-    # scatter is the one delivery whose f32 result depends on arrival
-    # order, and arrival order is the one thing that depends on D)
+    # ---- apply RMIs at local masters (live rows only, in canonical order:
+    # the additive scatter is the one delivery whose f32 result depends on
+    # arrival order, and arrival order is the one thing that depends on D)
     with jax.named_scope("d3.deliver"):
-        rmis_d = canon_msg_batch(rmis_d, part0, P_loc, N, router.n_parts)
-        agg_flat, cnt_flat, agg_dirty, busy = apply_rmis(
-            ls, rmis_d, part0, busy, delivery)
+        agg_flat, cnt_flat, agg_dirty, busy, n_deliver = deliver_rmis(
+            ls, rmis_d, part0, busy, delivery, router.n_parts)
 
     # ---- forward/update phase (psi), intra-layer window
     with jax.named_scope("d3.forward"):
@@ -587,6 +629,7 @@ def layer_tick_body(layer, params, topo: TopoState, ls: LayerState,
                       route_deferred=psum(rcpt.deferred),
                       route_dropped=psum(rcpt.dropped),
                       n_suppressed=psum(n_supp),
+                      deliver_rows=psum(n_deliver),
                       occ_bc_defer=occ_bc, occ_rmi_defer=occ_rmi,
                       route_peak=route_peak, outbox_part_peak=outbox_pp,
                       busy=busy)

@@ -3,7 +3,7 @@
 The streaming tick is split into six planes (ISSUE 2-5, 8, 9):
 
   * COMPUTE plane — pure part-local stages in `core/tick.py`
-    (`round_a_apply`, `round_b_emit`, `apply_rmis`, `forward_psi`) that
+    (`round_a_apply`, `round_b_emit`, `deliver_rmis`, `forward_psi`) that
     never write into another part's rows; every cross-part effect is a
     `MsgBatch` (core/events.py) addressed by global (part, slot).
   * ROUTING plane — a Router moves those records to whichever device

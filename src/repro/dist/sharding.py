@@ -146,8 +146,9 @@ def stats_pspecs(n_layers: int, axis: str = "data"):
     one = TickStats(broadcast_msgs=P(), reduce_msgs=P(), cross_part_msgs=P(),
                     emitted=P(), dropped=P(), wire_rows=P(),
                     route_deferred=P(), route_dropped=P(),
-                    n_suppressed=P(), occ_bc_defer=P(), occ_rmi_defer=P(),
-                    route_peak=P(), outbox_part_peak=P(), busy=P(axis))
+                    n_suppressed=P(), deliver_rows=P(), occ_bc_defer=P(),
+                    occ_rmi_defer=P(), route_peak=P(), outbox_part_peak=P(),
+                    busy=P(axis))
     return tuple(one for _ in range(n_layers))
 
 
@@ -222,7 +223,7 @@ def stage_stats_pspecs(n_rounds: int, stage_axis: str = "stage",
     s, b = P(stage_axis), P(stage_axis, axis)
     one = TickStats(broadcast_msgs=s, reduce_msgs=s, cross_part_msgs=s,
                     emitted=s, dropped=s, wire_rows=s, route_deferred=s,
-                    route_dropped=s, n_suppressed=s, occ_bc_defer=s,
-                    occ_rmi_defer=s, route_peak=s, outbox_part_peak=s,
-                    busy=b)
+                    route_dropped=s, n_suppressed=s, deliver_rows=s,
+                    occ_bc_defer=s, occ_rmi_defer=s, route_peak=s,
+                    outbox_part_peak=s, busy=b)
     return tuple(one for _ in range(n_rounds))

@@ -195,7 +195,7 @@ def rmi_apply_read(agg, cnt, idx, vec, dcnt, read_idx,
     full [R, d] mean table is never materialized, only the K picked rows.
 
     The streaming tick itself calls the two halves separately
-    (PallasDelivery.deliver_add in apply_rmis, .agg_read_rows in
+    (PallasDelivery.deliver_add in deliver_rmis, .agg_read_rows in
     forward_psi) because the read rows are only chosen AFTER the dirty
     flags exist; this single-call form is for callers that know their
     read rows up front, and is the tested contract
