@@ -10,9 +10,9 @@ window measure the p99 a client would see mid-recovery;
 `time-to-first-answer` is the wall clock from the moment of failure to
 the first post-failure answer landing on the host.
 
-Rows (one per driver):
+Row:
 
-  recovery[failstop,<driver>,D=4->2]
+  recovery[failstop,D=4->2]
     us_per_call   = recovery wall time (failure -> stream resumed), in us
     first_answer_ms = failure -> first post-failure answer
     p99_degraded_ms = answer p99 over queries issued while degraded
@@ -43,13 +43,13 @@ from repro.ft.checkpoint import CheckpointManager
 from repro.launch.mesh import make_stream_mesh, survivor_mesh
 from repro.serve.session import ServeSession
 
-cfg = ChaosConfig(driver={driver!r}, n_events={n_events})
+cfg = ChaosConfig(n_events={n_events})
 edges, feats, hubs = hub_heavy_stream(cfg)
 chunks = _chunks(cfg, edges)
 fail_at = min(cfg.fail_at_chunk, len(chunks) - 1)
 
 pipe = build_pipeline(cfg, make_stream_mesh(4))
-session = ServeSession(pipe, driver=cfg.driver, max_retries=2)
+session = ServeSession(pipe, super_ticks=1, max_retries=2)
 mgr = CheckpointManager(tempfile.mkdtemp(), keep=3)
 
 recovery_s = first_answer_s = None
@@ -91,17 +91,17 @@ print(f"RESULT,{{recovery_s:.6f}},{{first_answer_s:.6f}},{{p99:.3f}},"
 """
 
 
-def _worker(driver: str, n_events: int, timeout: int = 560):
+def _worker(n_events: int, timeout: int = 560):
     env = {"PYTHONPATH": str(REPO / "src"), "PATH": "/usr/bin:/bin",
            "HOME": "/root", "JAX_PLATFORMS": "cpu",
            "XLA_FLAGS": "--xla_force_host_platform_device_count=4 "
                         "--xla_backend_optimization_level=0"}
     r = subprocess.run(
         [sys.executable, "-c",
-         _WORKER.format(driver=driver, n_events=n_events)],
+         _WORKER.format(n_events=n_events)],
         env=env, capture_output=True, text=True, timeout=timeout)
     if r.returncode != 0:
-        raise RuntimeError(f"recovery worker driver={driver} failed:\n"
+        raise RuntimeError("recovery worker failed:\n"
                            + r.stderr[-2000:])
     for line in r.stdout.splitlines():
         if line.startswith("RESULT,"):
@@ -115,19 +115,16 @@ def _worker(driver: str, n_events: int, timeout: int = 560):
 
 def run(scale: str = "small"):
     n_events = {"small": 288, "full": 1152}[scale]
-    rows = []
-    for driver in ("tick", "super"):
-        res = _worker(driver, n_events)
-        rows.append(fmt_row(
-            f"recovery[failstop,{driver},D=4->2]",
-            res["recovery_s"] * 1e6,
-            f"recovery_s={res['recovery_s']:.3f};"
-            f"first_answer_ms={res['first_answer_s'] * 1e3:.1f};"
-            f"p99_degraded_ms={res['p99_ms']:.1f};"
-            f"dropped={res['dropped']};"
-            f"route_dropped={res['route_dropped']};"
-            f"replayed={res['replayed']};answered={res['answered']}"))
-    return rows
+    res = _worker(n_events)
+    return [fmt_row(
+        "recovery[failstop,D=4->2]",
+        res["recovery_s"] * 1e6,
+        f"recovery_s={res['recovery_s']:.3f};"
+        f"first_answer_ms={res['first_answer_s'] * 1e3:.1f};"
+        f"p99_degraded_ms={res['p99_ms']:.1f};"
+        f"dropped={res['dropped']};"
+        f"route_dropped={res['route_dropped']};"
+        f"replayed={res['replayed']};answered={res['answered']}")]
 
 
 if __name__ == "__main__":

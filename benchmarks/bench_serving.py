@@ -61,7 +61,7 @@ def build(mesh=None):
 
 
 def serve(mesh=None):
-    s = ServeSession(build(mesh), driver="super", super_ticks=SUPER_T)
+    s = ServeSession(build(mesh), super_ticks=SUPER_T)
     e_chunks, f_chunks = s.pipe.chunk_stream(edges, feats, TICK_EDGES)
     known = []
     t0 = time.perf_counter()

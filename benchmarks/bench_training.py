@@ -97,7 +97,7 @@ def run(scale: str = "small"):
     tcfg = TrainConfig(optimizer=sgd(), lr=0.05, batch_threshold=8)
     _, _, pipe = _build(n_nodes, n_total, train=tcfg,
                         train_cap=max(64, n_nodes // 2))
-    sess = TrainSession(pipe, driver="super", super_ticks=8)
+    sess = TrainSession(pipe, super_ticks=8)
     # warm the two scan shapes (T=1 probe + T=8 cruise) outside the
     # timed region: empty launches, nothing fires, nothing admits
     pipe.run_super_tick(T=1)

@@ -260,7 +260,7 @@ def serve(pipe, sizes: Sizes, edges, feats, seed: int, reshard=None):
     import numpy as np
     from repro.serve.session import ServeSession
 
-    session = ServeSession(pipe, driver="super", super_ticks=sizes.T)
+    session = ServeSession(pipe, super_ticks=sizes.T)
     rng = np.random.default_rng(seed + 1)
     e_chunks, f_chunks = pipe.chunk_stream(edges, feats, sizes.tick_edges)
     asked = {m: {} for m in MODES}

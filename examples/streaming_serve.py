@@ -96,7 +96,7 @@ def main():
     feats = {v: rng.normal(size=d_in).astype(np.float32)
              for v in range(args.nodes)}
     model, params, pipe = build(args.nodes, d_in, stage=args.stage)
-    session = ServeSession(pipe, driver="super", super_ticks=8)
+    session = ServeSession(pipe, super_ticks=8)
     mgr = CheckpointManager("results/serve_ckpt", keep=2, async_write=True)
 
     half = len(edges) // 2

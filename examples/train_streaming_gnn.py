@@ -44,7 +44,7 @@ def run_online(args, rng, feats, w_true, n_nodes, d_in, n_cls):
                          window=win.WindowConfig(kind=win.SESSION, interval=4))
     tcfg = TrainConfig(optimizer=adam(), lr=5e-3, batch_threshold=4)
     pipe = D3Pipeline(model, params, cfg, train=tcfg)
-    sess = TrainSession(pipe, driver="super", super_ticks=8)
+    sess = TrainSession(pipe, super_ticks=8)
 
     for phase in range(args.phases):
         edges = powerlaw_edges(rng, n_nodes, args.edges_per_phase)

@@ -130,19 +130,6 @@ for _cls, _fields in ((EdgeBatch, ["part", "edge_slot", "src_slot", "dst_slot",
     jax.tree_util.register_dataclass(_cls, data_fields=_fields, meta_fields=[])
 
 
-def empty_edge_batch(cap: int) -> EdgeBatch:
-    z = jnp.zeros((cap,), jnp.int32)
-    return EdgeBatch(part=z, edge_slot=z, src_slot=z, dst_slot=z,
-                     dst_master_part=z, dst_master_slot=z,
-                     valid=jnp.zeros((cap,), bool))
-
-
-def empty_repl_batch(cap: int) -> ReplBatch:
-    z = jnp.zeros((cap,), jnp.int32)
-    return ReplBatch(part=z, repl_slot=z, master_slot=z, rep_part=z,
-                     rep_slot=z, valid=jnp.zeros((cap,), bool))
-
-
 def empty_feat_batch(cap: int, d: int) -> FeatBatch:
     return FeatBatch(part=jnp.zeros((cap,), jnp.int32),
                      slot=jnp.zeros((cap,), jnp.int32),
@@ -150,14 +137,12 @@ def empty_feat_batch(cap: int, d: int) -> FeatBatch:
                      valid=jnp.zeros((cap,), bool))
 
 
-def vertex_batch_from_numpy(rows: dict, cap: int,
-                            device: bool = True) -> VertexBatch:
-    """device=False keeps numpy leaves — the super-tick staging path stacks
-    T batches on host and ships ONE transfer per field, so materializing
-    each tick's batch on device first would round-trip every row twice."""
+def vertex_batch_from_numpy(rows: dict, cap: int) -> VertexBatch:
+    """Padded numpy leaves, as every `*_from_numpy` constructor returns:
+    staging stacks a launch's T batches on the host and ships ONE
+    transfer per field, so no tick's batch is materialized on device."""
     n = len(rows["part"])
     assert n <= cap, f"vertex batch overflow: {n} > {cap}"
-    conv = jnp.asarray if device else (lambda a: a)
     p = np.zeros((cap,), np.int32)
     s = np.zeros((cap,), np.int32)
     m = np.zeros((cap,), bool)
@@ -166,20 +151,17 @@ def vertex_batch_from_numpy(rows: dict, cap: int,
     s[:n] = rows["slot"]
     m[:n] = rows["is_master"]
     v[:n] = True
-    return VertexBatch(part=conv(p), slot=conv(s),
-                       is_master=conv(m), valid=conv(v))
+    return VertexBatch(part=p, slot=s, is_master=m, valid=v)
 
 
-def edge_batch_from_numpy(rows: dict, cap: int,
-                          device: bool = True) -> EdgeBatch:
+def edge_batch_from_numpy(rows: dict, cap: int) -> EdgeBatch:
     n = len(rows["part"])
     assert n <= cap, f"edge batch overflow: {n} > {cap}"
-    conv = jnp.asarray if device else (lambda a: a)
 
     def pad(a, dtype=np.int32):
         out = np.zeros((cap,), dtype)
         out[:n] = a
-        return conv(out)
+        return out
 
     valid = np.zeros((cap,), bool)
     valid[:n] = True
@@ -187,26 +169,24 @@ def edge_batch_from_numpy(rows: dict, cap: int,
                      src_slot=pad(rows["src_slot"]), dst_slot=pad(rows["dst_slot"]),
                      dst_master_part=pad(rows["dst_master_part"]),
                      dst_master_slot=pad(rows["dst_master_slot"]),
-                     valid=conv(valid))
+                     valid=valid)
 
 
-def repl_batch_from_numpy(rows: dict, cap: int,
-                          device: bool = True) -> ReplBatch:
+def repl_batch_from_numpy(rows: dict, cap: int) -> ReplBatch:
     n = len(rows["part"])
     assert n <= cap, f"repl batch overflow: {n} > {cap}"
-    conv = jnp.asarray if device else (lambda a: a)
 
     def pad(a):
         out = np.zeros((cap,), np.int32)
         out[:n] = a
-        return conv(out)
+        return out
 
     valid = np.zeros((cap,), bool)
     valid[:n] = True
     return ReplBatch(part=pad(rows["part"]), repl_slot=pad(rows["repl_slot"]),
                      master_slot=pad(rows["master_slot"]),
                      rep_part=pad(rows["rep_part"]), rep_slot=pad(rows["rep_slot"]),
-                     valid=conv(valid))
+                     valid=valid)
 
 
 def concat_msg_batches(a: MsgBatch, b: MsgBatch) -> MsgBatch:
@@ -275,17 +255,9 @@ def stack_batches(batches):
         *batches)
 
 
-def empty_label_batch(cap: int) -> LabelBatch:
-    z = jnp.zeros((cap,), jnp.int32)
-    return LabelBatch(part=z, slot=z, label=z,
-                      valid=jnp.zeros((cap,), bool))
-
-
-def label_batch_from_numpy(parts, slots, labels, cap: int,
-                           device: bool = True) -> LabelBatch:
+def label_batch_from_numpy(parts, slots, labels, cap: int) -> LabelBatch:
     n = len(parts)
     assert n <= cap, f"label batch overflow: {n} > {cap}"
-    conv = jnp.asarray if device else (lambda a: a)
     p = np.zeros((cap,), np.int32)
     s = np.zeros((cap,), np.int32)
     y = np.zeros((cap,), np.int32)
@@ -294,15 +266,12 @@ def label_batch_from_numpy(parts, slots, labels, cap: int,
     s[:n] = slots
     y[:n] = labels
     v[:n] = True
-    return LabelBatch(part=conv(p), slot=conv(s), label=conv(y),
-                      valid=conv(v))
+    return LabelBatch(part=p, slot=s, label=y, valid=v)
 
 
-def feat_batch_from_numpy(parts, slots, feats, cap: int, d: int,
-                          device: bool = True) -> FeatBatch:
+def feat_batch_from_numpy(parts, slots, feats, cap: int, d: int) -> FeatBatch:
     n = len(parts)
     assert n <= cap, f"feat batch overflow: {n} > {cap}"
-    conv = jnp.asarray if device else (lambda a: a)
     p = np.zeros((cap,), np.int32)
     s = np.zeros((cap,), np.int32)
     f = np.zeros((cap, d), np.float32)
@@ -312,4 +281,4 @@ def feat_batch_from_numpy(parts, slots, feats, cap: int, d: int,
     if n:
         f[:n] = feats
     v[:n] = True
-    return FeatBatch(part=conv(p), slot=conv(s), feat=conv(f), valid=conv(v))
+    return FeatBatch(part=p, slot=s, feat=f, valid=v)

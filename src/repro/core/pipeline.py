@@ -9,28 +9,21 @@ layer l's outbox is layer l+1's inbox (the unrolled computation graph). The
 final outbox materializes into a device-side embedding sink — the paper's
 "materialized embedding table that can be further queried".
 
-Two drivers share ONE device program (`_tick_program`: topology apply + L
-staged layer ticks + sink update, all over the local part block):
-
-  * `tick()` — the per-tick REFERENCE path. One host round-trip per
-    micro-tick: rebuild numpy batches, launch one jitted tick, block on
-    the tick's stats. Simple to step through; use it for debugging, for
-    tests, and whenever events must be injected with tick-level control
-    flow on the host.
-
-  * `run_super_tick()` — the device-resident SUPER-TICK path (the paper's
-    always-on unrolled dataflow). The host pre-stages T micro-ticks of
-    padded batches (stacked along a leading T axis, one transfer per
-    field), then a single jitted `jax.lax.scan` advances all L layers
-    through all T ticks with the `PipelineCarry` donated at the jit
-    boundary and ONE host sync per super-tick (the summed stats +
-    quiescence flag read). A launch the host need not answer before the
-    next one stays PENDING: its sync comes after the next launch has
-    been staged and dispatched, so the host stages super-tick k+1 while
-    the device runs k (`run_super_tick` says when a launch syncs at
-    once and what settles a pending one). Same math, same event order —
-    the golden-equivalence tests pin the two drivers to the static
-    oracle.
+One driver launches ONE device program (`_tick_program`: topology apply +
+L staged layer ticks + sink update, all over the local part block) as the
+device-resident SUPER-TICK (the paper's always-on unrolled dataflow):
+`run_super_tick()` has the host pre-stage T micro-ticks of padded batches
+(stacked along a leading T axis, one transfer per field), then a single
+jitted `jax.lax.scan` advances all L layers through all T ticks with the
+`PipelineCarry` donated at the jit boundary and ONE host sync per
+super-tick (the summed stats + quiescence flag read). A launch the host
+need not answer before the next one stays PENDING: its sync comes after
+the next launch has been staged and dispatched, so the host stages
+super-tick k+1 while the device runs k (`run_super_tick` says when a
+launch syncs at once and what settles a pending one). `tick()`,
+`run_stream()` and `flush()` are that launch at T = 1, which is read at
+once. The golden-equivalence tests pin T = 1 and T = k launches to the
+static oracle.
 
 Distributed execution: pass `mesh=` (a 1-D ("data",) mesh, see
 `launch/mesh.py:make_stream_mesh`) and the SAME program runs inside one
@@ -57,7 +50,7 @@ reached: the 1-D program above runs byte-for-byte unchanged.
 Delivery backend: `PipelineConfig.delivery_backend` picks how routed
 records land in state — "xla" (reference scatters) or "pallas" (sorted
 segment-reduce kernels, `core/delivery.py`). Both backends run the same
-program under both drivers and both routers, golden-equivalent by test.
+program under both routers, golden-equivalent by test.
 
 Query plane: `PipelineConfig.query_cap > 0` puts a per-part pending
 point-query table (`repro/serve/query.py:QueryState`) in the carry and
@@ -113,7 +106,6 @@ Staging model / constraints:
 """
 from __future__ import annotations
 
-import warnings
 from dataclasses import dataclass, field, replace
 from functools import lru_cache, partial
 from typing import Optional
@@ -144,7 +136,7 @@ from repro.telemetry.trace import TRACE_DEVICE_COLS, TraceRecorder
 from repro.core.train_plane import (TrainConfig, init_train_state,
                                     train_pspecs, train_shardings,
                                     train_stage)
-from repro.serve.query import (KIND_EMBED, KIND_LINK, add_query_stats,
+from repro.serve.query import (KIND_LINK, add_query_stats,
                                empty_query_batch, init_query_state,
                                query_admit_stage, query_answer_stage,
                                query_batch_from_numpy, wire_width,
@@ -154,9 +146,8 @@ from repro.serve.query import (KIND_EMBED, KIND_LINK, add_query_stats,
 @dataclass(frozen=True)
 class Capacities:
     """Every RESOLVED per-tick budget of a (config, mesh) pair — the one
-    documented view of the capacity arithmetic that used to be spread
-    over `outbox()` / `query_admissions()` / `defer_rows()` (now thin
-    deprecated shims).  Read it once per launch site:
+    documented view of the capacity arithmetic.  Read it once per launch
+    site:
 
         caps = cfg.capacities(n_devices)
 
@@ -278,29 +269,6 @@ class PipelineConfig:
         per_dev = (lane_capacity if self.route_defer_cap is None
                    else self.route_defer_cap)
         return n_devices * per_dev
-
-    # deprecated accessors — the pre-ISSUE-8 API, kept as thin shims
-    def outbox(self) -> int:
-        """Deprecated: read `capacities().outbox` instead."""
-        warnings.warn("PipelineConfig.outbox() is deprecated — read "
-                      "capacities().outbox", DeprecationWarning,
-                      stacklevel=2)
-        return self._outbox()
-
-    def query_admissions(self) -> int:
-        """Deprecated: read `capacities().query_admissions` instead."""
-        warnings.warn("PipelineConfig.query_admissions() is deprecated — "
-                      "read capacities().query_admissions",
-                      DeprecationWarning, stacklevel=2)
-        return self._query_admissions()
-
-    def defer_rows(self, lane_capacity: int, n_devices: int) -> int:
-        """Deprecated: read the `*_defer_rows` fields of
-        `capacities(n_devices)` instead."""
-        warnings.warn("PipelineConfig.defer_rows() is deprecated — read "
-                      "capacities(n_devices).{bc,rmi,query}_defer_rows",
-                      DeprecationWarning, stacklevel=2)
-        return self._defer_rows(lane_capacity, n_devices)
 
     def validate(self, n_devices: int = 1, n_layers: Optional[int] = None,
                  local: bool = False) -> None:
@@ -732,23 +700,17 @@ class D3Pipeline:
         # query at the end of the last launch the host read
         self._pending: Optional[_Launch] = None
         self._queries_held = False
-        self._empty_feat = ev.empty_feat_batch(cfg.feat_cap, dims[0])
+        # host-resident empty batches for staging (stacked before upload)
         empty_rows = {k: np.zeros(0, np.int64) for k in
                       ("part", "edge_slot", "src_slot", "dst_slot",
                        "dst_master_part", "dst_master_slot")}
-        self._empty_edges = ev.edge_batch_from_numpy(
-            empty_rows, cfg.edge_tick_cap)
-        # host-resident twin for super-tick staging (stacked before upload)
-        self._empty_edges_np = ev.edge_batch_from_numpy(
-            empty_rows, cfg.edge_tick_cap, device=False)
+        self._empty_edges = ev.edge_batch_from_numpy(empty_rows,
+                                                     cfg.edge_tick_cap)
         self._empty_queries = empty_query_batch(caps.query_admissions,
                                                 self.d_out)
-        self._empty_queries_np = empty_query_batch(caps.query_admissions,
-                                                   self.d_out, device=False)
         z0 = np.zeros(0, np.int64)
-        self._empty_labels = ev.empty_label_batch(cfg.train_cap)
-        self._empty_labels_np = ev.label_batch_from_numpy(
-            z0, z0, z0, cfg.train_cap, device=False)
+        self._empty_labels = ev.label_batch_from_numpy(z0, z0, z0,
+                                                       cfg.train_cap)
         self._answer_log: list = []    # host-side answered-row columns
         # telemetry plane: the trace recorder + straggler feed
         if cfg.telemetry:
@@ -919,7 +881,7 @@ class D3Pipeline:
 
     def _unstack_stats(self, host_stats):
         """Per-ROUND stacked stats ([S] scalars / [S, n_parts] busy) ->
-        the 1-D drivers' per-LAYER list: layer l = r*S + s sits at index
+        the 1-D program's per-LAYER list: layer l = r*S + s sits at index
         s of round r's stack."""
         out = []
         for l in range(len(self.layers)):
@@ -949,7 +911,7 @@ class D3Pipeline:
 
     def _ring_occupancy_host(self) -> int:
         """Valid rows still in flight between stages (0 on a 1-D mesh) —
-        the host-driver flush must not terminate over them."""
+        a reshard that changes the stage count must not drop them."""
         if self.stage_ring is None:
             return 0
         return int(jnp.sum(self.stage_ring[..., -1] > 0.5))
@@ -1220,19 +1182,17 @@ class D3Pipeline:
         return {k: np.asarray(v) for k, v in rows.items()}
 
     def _build_batches(self, edges: Optional[np.ndarray],
-                       feats: Optional[list], device: bool = True,
-                       queries: Optional[list] = None,
-                       issue_tick: Optional[int] = None,
-                       labels: Optional[list] = None):
-        """One tick's padded batches. device=False keeps numpy leaves for
-        the super-tick staging path (stack first, upload once).
+                       feats: Optional[list], queries: Optional[list],
+                       issue_tick: int, labels: Optional[list]):
+        """One tick's padded batches, as numpy leaves: staging stacks a
+        launch's ticks first and uploads each field once.
         labels: [(vid, gold_class), ...] training-plane admissions —
         resolved to master coordinates; vids the partitioner has never
         seen are silently skipped (no master slot to label)."""
         with self.span("d3.stage.partition"):
             rows = self._partition(edges, feats)
         with self.span("d3.stage.pack"):
-            return self._pack(*rows, device, queries, issue_tick, labels)
+            return self._pack(*rows, queries, issue_tick, labels)
 
     def _partition(self, edges, feats):
         """The partitioner's share of staging: place the tick's edges
@@ -1260,33 +1220,30 @@ class D3Pipeline:
             r_rows, v_rows = r2, v2
         return e_rows, r_rows, v_rows, (f_parts, f_slots, f_vecs)
 
-    def _pack(self, e_rows, r_rows, v_rows, f_rows, device, queries,
-              issue_tick, labels):
+    def _pack(self, e_rows, r_rows, v_rows, f_rows, queries, issue_tick,
+              labels):
         """Pad the partitioned rows into the tick's fixed-capacity
         batches (queries and labels resolved to master slots here)."""
         cfg = self.cfg
         f_parts, f_slots, f_vecs = f_rows
-        eb = (ev.edge_batch_from_numpy(e_rows, cfg.edge_tick_cap, device)
-              if e_rows is not None
-              else (self._empty_edges if device else self._empty_edges_np))
-        rb = ev.repl_batch_from_numpy(r_rows, max(2 * cfg.edge_tick_cap, 1),
-                                      device)
+        eb = (ev.edge_batch_from_numpy(e_rows, cfg.edge_tick_cap)
+              if e_rows is not None else self._empty_edges)
+        rb = ev.repl_batch_from_numpy(r_rows, max(2 * cfg.edge_tick_cap, 1))
         vb = ev.vertex_batch_from_numpy(v_rows, max(2 * cfg.edge_tick_cap +
-                                                    cfg.feat_cap, 1), device)
+                                                    cfg.feat_cap, 1))
         fb = ev.feat_batch_from_numpy(
             np.asarray(f_parts), np.asarray(f_slots),
             np.asarray(f_vecs, np.float32).reshape(len(f_parts), -1)
             if f_parts else np.zeros((0, 1)),
-            cfg.feat_cap, self.states[0].feat.shape[-1], device)
+            cfg.feat_cap, self.states[0].feat.shape[-1])
         if queries:
             assert cfg.query_cap > 0, \
                 "queries submitted but PipelineConfig.query_cap=0"
-            q_rows = self._resolve_queries(
-                queries, self.now if issue_tick is None else issue_tick)
+            q_rows = self._resolve_queries(queries, issue_tick)
             qb = query_batch_from_numpy(q_rows, cfg._query_admissions(),
-                                        self.d_out, device)
+                                        self.d_out)
         else:
-            qb = (self._empty_queries if device else self._empty_queries_np)
+            qb = self._empty_queries
         if labels:
             assert cfg.train_cap > 0, \
                 "labels submitted but PipelineConfig.train_cap=0"
@@ -1300,9 +1257,9 @@ class D3Pipeline:
                 l_gold.append(int(y))
             lb = ev.label_batch_from_numpy(
                 np.asarray(l_parts, np.int64), np.asarray(l_slots, np.int64),
-                np.asarray(l_gold, np.int64), cfg.train_cap, device)
+                np.asarray(l_gold, np.int64), cfg.train_cap)
         else:
-            lb = (self._empty_labels if device else self._empty_labels_np)
+            lb = self._empty_labels
         return eb, rb, vb, fb, qb, lb
 
     # ---------------------------------------------------------- device side
@@ -1310,7 +1267,8 @@ class D3Pipeline:
              feats: Optional[list] = None, window=None,
              queries: Optional[list] = None,
              labels: Optional[list] = None):
-        """One micro-tick through the full pipeline.
+        """One micro-tick through the full pipeline: a one-tick launch of
+        `run_super_tick`, read at once. Returns its per-layer TickStats.
 
         queries: optional [(qid, kind, vid, [vid2,] consistent), ...]
         point-query admissions for this tick (needs cfg.query_cap > 0);
@@ -1319,78 +1277,9 @@ class D3Pipeline:
         admissions for this tick (needs cfg.train_cap > 0 and a
         TrainConfig); training progress is read via `train_stats()`.
         """
-        cfg = self.cfg
-        wconf = window or cfg.window
-        tick0 = self.now
-        outbox_cap = cfg.capacities().outbox
-        counts = (len(edges) if edges is not None else 0,
-                  len(feats) if feats else 0,
-                  len(queries) if queries else 0,
-                  len(labels) if labels else 0)
-        self.metrics.launches += 1
-        with self.span("d3.launch", step=True) as launch:
-            s0 = self._clock.total("d3.stage")
-            with self.span("d3.stage"):
-                batches = self._build_batches(edges, feats, queries=queries,
-                                              labels=labels)
-            host_s = self._clock.total("d3.stage") - s0
-            eb, rb, vb, fb, qb, lb = batches
-            self._count_staged([counts], fb, batches)
-            now = jnp.asarray(self.now, jnp.int32)
-            if self.n_stages > 1:
-                with self.span("d3.dispatch"):
-                    (self.topo, new_states, self.sink, self.sink_seen,
-                     self.queries, self.stage_ring, stats_all, idle, answers,
-                     qstats, new_ts, occ) = _tick_jit_2d(
-                        self.rounds, self._staged_params(), self.topo,
-                        tuple(self.states), self.sink, self.sink_seen,
-                        self.queries, self.stage_ring, fb, eb, rb, vb, qb,
-                        lb, self.train_state, now, wconf, outbox_cap,
-                        self.router, self.delivery, self.mesh,
-                        cfg.delta_eps, self.train_cfg, self._head,
-                        self._acts, cfg.telemetry)
-                self.states = list(new_states)
-                self.train_state = new_ts
-                self._sync_params_from_train()
-                self.now += 1
-                with self.span("d3.sync"):
-                    stats_all, idle, qstats, answers, occ = jax.device_get(
-                        (stats_all, idle, qstats, answers, occ))
-                with self.span("d3.harvest"):
-                    self._harvest_answers(answers)
-                    per_layer = self._unstack_stats(stats_all)
-                    self.metrics.stage_idle += int(np.sum(idle))
-                    occ_np = (np.asarray(occ) if self.trace is not None
-                              else None)
-                    self._accumulate(per_layer, qstats=qstats,
-                                     occ_rows=occ_np)
-                    self._trace_ticks(occ_np, tick0, launch.elapsed(),
-                                      host_s, counts, per_layer)
-                return per_layer
-            with self.span("d3.dispatch"):
-                (self.topo, new_states, self.sink, self.sink_seen,
-                 self.queries, stats_all, answers, qstats, new_ts,
-                 occ) = _tick_jit(
-                    tuple(self.layers), self.params, self.topo,
-                    tuple(self.states), self.sink, self.sink_seen,
-                    self.queries, fb, eb, rb, vb, qb, lb, self.train_state,
-                    now, wconf, outbox_cap, self.router, self.delivery,
-                    self.mesh, cfg.delta_eps, self.train_cfg, self._head,
-                    cfg.telemetry)
-            self.states = list(new_states)
-            self.train_state = new_ts
-            self._sync_params_from_train()
-            self.now += 1
-            with self.span("d3.sync"):
-                stats_all, qstats, answers, occ = jax.device_get(
-                    (stats_all, qstats, answers, occ))
-            with self.span("d3.harvest"):
-                self._harvest_answers(answers)
-                occ_np = np.asarray(occ) if self.trace is not None else None
-                self._accumulate(stats_all, qstats=qstats, occ_rows=occ_np)
-                self._trace_ticks(occ_np, tick0, launch.elapsed(), host_s,
-                                  counts, stats_all)
-            return list(stats_all)
+        return list(self.run_super_tick(
+            [edges], [feats], T=1, window=window, query_chunks=[queries],
+            label_chunks=[labels])[0])
 
     def _sync_params_from_train(self) -> None:
         """Mirror the live trained parameters back into `self.params` so
@@ -1416,9 +1305,8 @@ class D3Pipeline:
 
     def _harvest_answers(self, answers) -> None:
         """Pull this launch's answered rows (valid mask) into the host-side
-        answer log. `answers` leaves are [A, ...] (per-tick driver) or
-        [T, A, ...] (super-tick ys); zero-capacity leaves mean the query
-        plane is off."""
+        answer log. `answers` leaves are [T, A, ...] (the scan's ys);
+        zero-capacity leaves mean the query plane is off."""
         if answers.valid.size == 0:
             return
         a = jax.device_get(answers)
@@ -1449,11 +1337,10 @@ class D3Pipeline:
         return {k: np.concatenate([chunk[k] for chunk in log])
                 for k in log[0]}
 
-    def _accumulate(self, stats_all, ticks: int = 1, qstats=None,
+    def _accumulate(self, stats_all, ticks: int, qstats=None,
                     occ_rows=None):
-        """Fold per-layer stats into StreamMetrics — one tick's stats from
-        the per-tick driver, or `ticks` micro-ticks' summed stats from a
-        super-tick (the counters are additive either way).
+        """Fold a launch's per-layer stats, summed over its `ticks`
+        micro-ticks, into StreamMetrics.
 
         occ_rows (telemetry plane): [ticks, len(TRACE_DEVICE_COLS)] int
         per-tick occupancy rows off the device — backlog integrals add,
@@ -1498,26 +1385,23 @@ class D3Pipeline:
                                       or int(qstats.wire_backlog))
 
     def _trace_ticks(self, occ_rows, tick0, wall_s, host_s, counts,
-                     stats_all, ticks: int = 1, amortized: int = 0):
+                     stats_all, ticks: int):
         """Telemetry-plane host side: append per-tick trace rows and feed
         the straggler mitigator. No-op when telemetry is off.
 
-        occ_rows: [ticks, C] device occupancy rows; counts: per-tick
-        (edges, feats, queries, labels) ingest tuples — a single tuple on
-        the per-tick driver, a list of `ticks` tuples on the scan driver
-        (whose wall and staging times are spread evenly over its ticks,
-        amortized=1)."""
+        occ_rows: [ticks, C] device occupancy rows; counts: one (edges,
+        feats, queries, labels) ingest tuple per tick. A launch of several
+        ticks spreads its wall and staging times evenly over them
+        (amortized=1)."""
         if self.trace is None:
             return
         occ = np.asarray(occ_rows).reshape(-1, len(TRACE_DEVICE_COLS))
-        rows = [counts] if ticks == 1 else list(counts)
         per = wall_s / max(ticks, 1)
         host_per = host_s / max(ticks, 1)
-        for i in range(ticks):
-            e, f, q, l = rows[i]
+        for i, (e, f, q, l) in enumerate(counts):
             self.trace.append(
                 {"tick": tick0 + i, "ticks": 1, "wall_s": per,
-                 "host_s": host_per, "amortized": amortized,
+                 "host_s": host_per, "amortized": int(ticks > 1),
                  "wire_bytes": self._wire_bytes_per_tick,
                  "edges_in": e, "feats_in": f, "queries_in": q,
                  "labels_in": l},
@@ -1549,9 +1433,9 @@ class D3Pipeline:
                      feat_with_first_edge: bool = True, seen=None):
         """Cut an edge stream into micro-tick chunks + aligned feature
         events (each vertex's feature fires in the tick of its first edge).
-        Shared by both drivers so their tick boundaries always agree —
-        serving loops that chunk a stream in several calls pass a
-        persistent `seen` set so features still fire exactly once."""
+        One cut whatever the launch size, so tick boundaries agree across
+        launch sizes — serving loops that chunk a stream in several calls
+        pass a persistent `seen` set so features still fire exactly once."""
         seen = set() if seen is None else seen
         e_chunks, f_chunks = [], []
         with self.span("d3.chunk"):
@@ -1575,9 +1459,8 @@ class D3Pipeline:
 
         Returns (fb, eb, rb, vb, qb, lb) pytrees with a leading [T] axis —
         the xs of the super-tick scan. Host partitioner state advances tick
-        by tick exactly as the per-tick driver would have advanced it;
-        query issue ticks are stamped with the tick the scan will admit
-        them in.
+        by tick, as T one-tick launches would advance it; query issue
+        ticks are stamped with the tick the scan will admit them in.
         """
         with self.span("d3.stage"):
             ebs, rbs, vbs, fbs, qbs, lbs = [], [], [], [], [], []
@@ -1585,8 +1468,7 @@ class D3Pipeline:
                     zip(edge_chunks, feat_chunks, query_chunks,
                         label_chunks)):
                 eb, rb, vb, fb, qb, lb = self._build_batches(
-                    edges_t, feats_t, device=False, queries=queries_t,
-                    issue_tick=self.now + i, labels=labels_t)
+                    edges_t, feats_t, queries_t, self.now + i, labels_t)
                 ebs.append(eb)
                 rbs.append(rb)
                 vbs.append(vb)
@@ -1621,14 +1503,16 @@ class D3Pipeline:
 
         The launch stages, dispatches, then reads the launch before it
         if that one is still pending. It syncs at once itself when the
-        host must see its outputs before the next launch: it admits a
-        query or a label, a query admitted earlier was still held when
-        the last read launch ended, the telemetry plane is on (trace
-        rows take the launch's own wall time), or it stages no edge or
-        feature event (a flush launch, read for its quiet counter).
+        host must see its outputs before the next launch: it runs one
+        tick (a host that steps tick by tick reads each: `tick`,
+        `flush`, one-tick sessions), it admits a query or a label, a
+        query admitted earlier was still held when the last read launch
+        ended, the telemetry plane is on (trace rows take the launch's
+        own wall time), or it stages no edge or feature event (a flush
+        launch, read for its quiet counter).
         Otherwise it stays PENDING: the next launch is staged while the
         device runs it, and reading the returned value, `metrics`,
-        `settle()`, the next launch, `flush_super`, `flush`, `tick`,
+        `settle()`, the next launch (`tick` and `flush` launch too),
         `train_stats`, `reshard`, `read_nodes` / `embeddings`,
         `save_trace` or a checkpoint save reads it. A pending launch
         carries no answers, so `drain_answers` need not wait for it. An
@@ -1652,7 +1536,7 @@ class D3Pipeline:
                    len(l) if l else 0)
                   for e, f, q, l in zip(edge_chunks, feat_chunks,
                                         query_chunks, label_chunks)]
-        sync_now = (self.cfg.telemetry or self._queries_held
+        sync_now = (T == 1 or self.cfg.telemetry or self._queries_held
                     or any(q or l for _, _, q, l in counts)
                     or not any(e or f for e, f, _, _ in counts))
         launch = _Launch(ticks=T, tick0=self.now, counts=counts)
@@ -1744,13 +1628,14 @@ class D3Pipeline:
                              occ_rows=occ_np)
             self._trace_ticks(occ_np, launch.tick0, launch.span.elapsed(),
                               launch.host_s, launch.counts, stats,
-                              ticks=launch.ticks, amortized=1)
+                              launch.ticks)
         launch.result = (stats, int(quiet))
 
     def run_stream_super(self, edges: np.ndarray, feats: dict,
                          tick_edges: int = 256, super_ticks: int = 16,
                          feat_with_first_edge: bool = True):
-        """`run_stream`, but T micro-ticks per device launch.
+        """Stream an edge list (+ node features, see `run_stream`)
+        through the pipeline, `super_ticks` micro-ticks per launch.
 
         Cuts the stream into `tick_edges`-sized micro-ticks, groups them
         into super-ticks of `super_ticks` ticks each (the tail group is
@@ -1766,7 +1651,9 @@ class D3Pipeline:
 
     def flush_super(self, max_ticks: int = 64, T: int = 8,
                     drain: bool = True) -> int:
-        """`flush`, super-tick style: empty ticks until device quiescence.
+        """Run empty ticks, T a launch, until device quiescence; returns
+        the ticks run (a multiple of T, or max_ticks). `drain` as in
+        `flush`.
 
         The consecutive-quiet counter lives in the scan carry; the host
         reads it once per super-tick and re-seeds the next launch through
@@ -1790,37 +1677,25 @@ class D3Pipeline:
 
     def run_stream(self, edges: np.ndarray, feats: dict,
                    tick_edges: int = 256, feat_with_first_edge: bool = True):
-        """Stream an edge list (+ node features) through the pipeline.
+        """Stream an edge list (+ node features) through the pipeline, one
+        micro-tick a launch (`run_stream_super` at super_ticks=1).
 
         feats: {vid: np.ndarray} — each vertex's feature event is injected
         in the tick its first edge appears (feature stream aligned with the
         topology stream, as in the paper's temporal edge-list datasets).
         """
-        e_chunks, f_chunks = self.chunk_stream(edges, feats, tick_edges,
-                                               feat_with_first_edge)
-        for chunk, f_events in zip(e_chunks, f_chunks):
-            self.tick(chunk, f_events)
-        return self
+        return self.run_stream_super(
+            edges, feats, tick_edges=tick_edges, super_ticks=1,
+            feat_with_first_edge=feat_with_first_edge)
 
     def flush(self, max_ticks: int = 64, drain: bool = True) -> int:
-        """Run empty ticks until the TerminationCoordinator fires.
+        """Run empty ticks, one a launch (`flush_super` at T=1), until the
+        pipeline is quiescent; returns the ticks run.
 
         drain=True forces pending windows due immediately (streaming
         eviction) — the training coordinator's flush semantics (§4.3.1).
         drain=False waits for the scheduled timers (pure §5.3 behaviour)."""
-        term = TerminationCoordinator()
-        override = win.WindowConfig(kind=win.STREAMING) if drain else None
-        with self.span("d3.drain"):
-            for i in range(max_ticks):
-                self.metrics.drain_launches += 1
-                stats = self.tick(window=override)
-                # in-flight inter-stage rows are pending work the host
-                # cannot see in the layer states (0 on a 1-D mesh)
-                if term.observe(self.states, stats, queries=self.queries,
-                                extra_work=self._ring_occupancy_host()):
-                    return i + 1
-        raise RuntimeError("pipeline failed to terminate "
-                           f"within {max_ticks} flush ticks")
+        return self.flush_super(max_ticks, T=1, drain=drain)
 
     # ------------------------------------------------------------- queries
     def read_nodes(self, vids) -> dict:
@@ -1945,8 +1820,9 @@ def _tick_program(layers, params, topo, states, sink, sink_seen, queries,
     (end-of-tick, ISSUE 8; `tcfg is None` — the train_cap=0 default —
     compiles the whole plane away and the program is bit-for-bit the
     four-plane tick). Runs directly under the LocalRouter and as the
-    shard_map body under the MeshRouter — the two drivers, the two
-    routers and the two delivery backends all share this program."""
+    shard_map body under the MeshRouter — the two routers and the two
+    delivery backends share this program, one step of the super-tick
+    scan."""
     part0 = router.part0()
     with jax.named_scope("d3.topo"):
         topo = st.apply_vertex_batch(topo, vb, part0)
@@ -2008,38 +1884,6 @@ def _tick_program(layers, params, topo, states, sink, sink_seen, queries,
            else _zero_occ_row())
     return (topo, tuple(new_states), sink, sink_seen, queries,
             tuple(stats_all), ans, qstats, new_ts, occ)
-
-
-@partial(jax.jit, static_argnames=("layers", "wconf", "outbox_cap",
-                                   "router", "delivery", "mesh",
-                                   "delta_eps", "tcfg", "head",
-                                   "telemetry"))
-def _tick_jit(layers, params, topo, states, sink, sink_seen, queries,
-              inbox, eb, rb, vb, qb, lb, ts, now, wconf, outbox_cap,
-              router, delivery, mesh, delta_eps=0.0, tcfg=None, head=None,
-              telemetry=False):
-    """The per-tick driver's device program (reference path)."""
-    def prog(params, topo, states, sink, sink_seen, queries, inbox, eb,
-             rb, vb, qb, lb, ts, now):
-        return _tick_program(
-            layers, params, topo, states, sink, sink_seen, queries, inbox,
-            eb, rb, vb, qb, lb, now, wconf, outbox_cap, router, delivery,
-            delta_eps, ts, tcfg, head, telemetry)
-
-    if mesh is None:
-        return prog(params, topo, states, sink, sink_seen, queries, inbox,
-                    eb, rb, vb, qb, lb, ts, now)
-    cp = carry_pspecs(len(layers))
-    tspec = train_pspecs(ts) if tcfg is not None else P()
-    sharded = jax.shard_map(
-        prog, mesh=mesh,
-        in_specs=(P(), cp.topo, cp.layers, cp.sink, cp.sink_seen,
-                  cp.queries, P(), P(), P(), P(), P(), P(), tspec, P()),
-        out_specs=(cp.topo, cp.layers, cp.sink, cp.sink_seen, cp.queries,
-                   stats_pspecs(len(layers)), P("data"), P(), tspec, P()),
-        check_vma=False)
-    return sharded(params, topo, states, sink, sink_seen, queries, inbox,
-                   eb, rb, vb, qb, lb, ts, now)
 
 
 @partial(jax.jit, static_argnames=("layers", "wconf", "outbox_cap",
@@ -2305,38 +2149,6 @@ def _tick_program_2d(rounds, params, topo, states, sink, sink_seen,
     return (topo, tuple(ex(s) for s in new_states), sink, sink_seen,
             queries, new_ring, tuple(ex(s) for s in stats_all), idle_v,
             ans, qstats, new_ts, occ)
-
-
-@partial(jax.jit, static_argnames=("rounds", "wconf", "outbox_cap",
-                                   "router", "delivery", "mesh",
-                                   "delta_eps", "tcfg", "head", "acts",
-                                   "telemetry"))
-def _tick_jit_2d(rounds, params, topo, states, sink, sink_seen, queries,
-                 ring, inbox, eb, rb, vb, qb, lb, ts, now, wconf,
-                 outbox_cap, router, delivery, mesh, delta_eps=0.0,
-                 tcfg=None, head=None, acts=None, telemetry=False):
-    """The per-tick driver's device program on the 2-D mesh."""
-    def prog(params, topo, states, sink, sink_seen, queries, ring, inbox,
-             eb, rb, vb, qb, lb, ts, now):
-        return _tick_program_2d(
-            rounds, params, topo, states, sink, sink_seen, queries, ring,
-            inbox, eb, rb, vb, qb, lb, now, wconf, outbox_cap, router,
-            delivery, delta_eps, ts, tcfg, head, acts, telemetry)
-
-    cp = stage_carry_pspecs(len(rounds))
-    tspec = train_pspecs(ts) if tcfg is not None else P()
-    pspec = jax.tree.map(lambda _: P("stage"), params)
-    sharded = jax.shard_map(
-        prog, mesh=mesh,
-        in_specs=(pspec, cp.topo, cp.layers, cp.sink, cp.sink_seen,
-                  cp.queries, cp.stage_ring, P(), P(), P(), P(), P(),
-                  P(), tspec, P()),
-        out_specs=(cp.topo, cp.layers, cp.sink, cp.sink_seen, cp.queries,
-                   cp.stage_ring, stage_stats_pspecs(len(rounds)),
-                   P("stage"), P("data"), P(), tspec, P()),
-        check_vma=False)
-    return sharded(params, topo, states, sink, sink_seen, queries, ring,
-                   inbox, eb, rb, vb, qb, lb, ts, now)
 
 
 @partial(jax.jit, static_argnames=("rounds", "wconf", "outbox_cap",

@@ -6,12 +6,10 @@ last collection and (b) no scheduled timers (window deadlines still
 pending). Used to compute bounded-run "runtime" (paper Fig. 4c) and to
 flush the pipeline before training (§4.3.1).
 
-Two observation paths:
-  * per-tick (host): `observe` pulls each tick's stats to the host — one
-    blocking sync per tick, fine for the reference driver;
-  * super-tick (device): `quiet_update` advances a consecutive-quiet-tick
-    counter INSIDE the `lax.scan` body, and the driver reads the resulting
-    quiescence flag exactly once per super-tick (`observe_flag`).
+Observation runs on the device: `quiet_update` advances a
+consecutive-quiet-tick counter INSIDE the super-tick `lax.scan` body, and
+the driver reads the resulting counter exactly once per launch
+(`TerminationCoordinator.observe_flag`), a one-tick launch included.
 """
 from __future__ import annotations
 
@@ -22,8 +20,8 @@ from repro.core.tick import has_work
 
 def moved_msgs(tick_stats):
     """Total MOVEMENT of one layer's TickStats: emissions + reduces +
-    broadcasts. This is THE movement vote both observation paths share
-    (`quiet_update` on device, `TerminationCoordinator.observe` on host).
+    broadcasts. This is THE movement vote of `quiet_update`, and the
+    training plane's `moved` count.
 
     TickStats.n_suppressed is deliberately EXCLUDED: a delta-gated
     (suppressed-but-pending) vertex counts as QUIET. Suppression clears
@@ -46,8 +44,7 @@ def pending_work(layer_states, queries=None, extra_work=None) -> jnp.ndarray:
     between stages hold quiescence off exactly like deferred wire rows).
 
     This is THE single aggregation every quiescence / silence gate uses —
-    `quiet_update` (super-tick scan), `TerminationCoordinator.observe`
-    (per-tick flush) and the query plane's silence gates
+    `quiet_update` (super-tick scan) and the query plane's silence gates
     (serve/query.py:_plane_work). A new carried-work source added here
     reaches all of them at once; added anywhere else it silently weakens
     some gate."""
@@ -108,20 +105,6 @@ class TerminationCoordinator:
         chaining super-ticks (`run_super_tick(quiet0=...)`): quiescence
         streaks must survive the host round-trip between launches."""
         return self._quiet
-
-    def observe(self, layer_states, tick_stats, queries=None,
-                extra_work=None) -> bool:
-        """Feed one tick's observations; True once terminated.
-        queries: optional QueryState — votes the wire-lane backlog as
-        pending work (same `pending_work` aggregation as the device
-        paths). extra_work: host-side in-flight count (the per-tick
-        driver passes the hybrid pipeline's stage-ring occupancy)."""
-        moved = any(int(moved_msgs(s)) for s in tick_stats)
-        if moved or bool(pending_work(layer_states, queries, extra_work)):
-            self._quiet = 0
-        else:
-            self._quiet += 1
-        return self._quiet >= self.quiet_sweeps
 
     def observe_flag(self, quiet_ticks: int) -> bool:
         """Feed a device-computed consecutive-quiet counter (one host read

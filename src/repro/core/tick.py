@@ -520,8 +520,8 @@ def layer_tick_body(layer, params, topo: TopoState, ls: LayerState,
 
     This is the un-jitted body so the super-tick driver can inline all L
     layers inside one `lax.scan` step (and the mesh path can wrap the whole
-    program in one `shard_map`); the per-tick reference path wraps it in
-    `layer_tick` below.
+    program in one `shard_map`); `layer_tick` below jits one layer on
+    its own.
     """
     if router is None:
         router = LocalRouter(n_parts=ls.feat.shape[0])

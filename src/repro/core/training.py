@@ -310,8 +310,11 @@ class TrainingCoordinator:
             nf, nh, agg, cnt = rebuild_layer(layer, pipe.params[f"l{li}"],
                                              pipe.topo, feat, has)
             st = pipe.layer_state(li)
+            # x_sent/has_sent get buffers of their own: the next launch
+            # donates the carry, and a buffer may be donated only once
             pipe.set_layer_state(li, LayerState(
-                feat=feat, has_feat=has, x_sent=feat, has_sent=has,
+                feat=feat, has_feat=has, x_sent=jnp.copy(feat),
+                has_sent=jnp.copy(has),
                 agg=agg, agg_cnt=cnt,
                 red_pending=jnp.zeros_like(st.red_pending),
                 red_deadline=st.red_deadline,

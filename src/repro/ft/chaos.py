@@ -66,7 +66,6 @@ class ChaosConfig:
     n_parts: int = 4
     node_cap: int = 64
     query_cap: int = 8
-    driver: str = "tick"             # "tick" | "super"
     # fault schedule (chunk-indexed)
     fail_at_chunk: int = 10          # fail-stop strikes BEFORE this chunk
                                      # (NOT on a cut: chunks since the
@@ -128,12 +127,7 @@ def build_pipeline(cfg: ChaosConfig, mesh=None, n_stages: int = 1,
 
 def _advance(session: ServeSession, chunk, feats):
     rows = _feat_rows(chunk, feats) if len(chunk) else None
-    ed = chunk if len(chunk) else None
-    if session.driver == "tick":
-        session.advance(ed, rows)
-    else:
-        session.advance_super([ed] if ed is not None else None,
-                              [rows] if rows is not None else None, T=1)
+    session.advance(chunk if len(chunk) else None, rows)
 
 
 # ------------------------------------------------------------- scenarios
@@ -141,7 +135,7 @@ def scenario_failstop(cfg: ChaosConfig, ckpt_dir, d_old: int = 4,
                       d_new: int = 2, n_stages: int = 1) -> dict:
     """Hub-heavy spike + fail-stop shard loss mid-stream.
 
-    Oracle first: the SAME stream, queries, and driver, uninterrupted on
+    Oracle first: the SAME stream and queries, uninterrupted on
     the d_old grid. Then the chaos run: consistent-cut checkpoints every
     `checkpoint_every` chunks; before chunk `fail_at_chunk` the shards in
     `lose_shards` fail-stop — the session degrades, the last checkpoint
@@ -159,7 +153,7 @@ def scenario_failstop(cfg: ChaosConfig, ckpt_dir, d_old: int = 4,
 
     def _run(mesh_fn, fail: bool):
         pipe = build_pipeline(cfg, mesh_fn(), n_stages=n_stages)
-        session = ServeSession(pipe, driver=cfg.driver, max_retries=2)
+        session = ServeSession(pipe, super_ticks=1, max_retries=2)
         mgr = (CheckpointManager(Path(ckpt_dir) / "chaos", keep=3)
                if fail else None)
         qids = None
@@ -214,7 +208,7 @@ def scenario_truncated_checkpoint(cfg: ChaosConfig, ckpt_dir) -> dict:
     edges, feats, _ = hub_heavy_stream(cfg)
     chunks = _chunks(cfg, edges)[:4]
     pipe = build_pipeline(cfg)
-    session = ServeSession(pipe, driver=cfg.driver)
+    session = ServeSession(pipe, super_ticks=1)
     mgr = CheckpointManager(Path(ckpt_dir) / "torn", keep=3)
     for i, chunk in enumerate(chunks):
         _advance(session, chunk, feats)
@@ -257,10 +251,7 @@ def scenario_slow_shard(cfg: ChaosConfig, d_old: int = 4,
     mitigated_at = None
     for i, chunk in enumerate(chunks):
         rows = _feat_rows(chunk, feats)
-        if cfg.driver == "tick":
-            pipe.tick(chunk, rows)
-        else:
-            pipe.run_super_tick([chunk], [rows])
+        pipe.tick(chunk, rows)
         if plan is None:
             # deterministic injected walls: the slow shard stretches the
             # lock-step tick by slow_factor and shows the highest busy.
@@ -300,7 +291,7 @@ def scenario_admission_storm(cfg: ChaosConfig) -> dict:
     edges, feats, _ = hub_heavy_stream(cfg)
     chunks = _chunks(cfg, edges)
     pipe = build_pipeline(cfg)
-    session = ServeSession(pipe, driver=cfg.driver, max_retries=4,
+    session = ServeSession(pipe, super_ticks=1, max_retries=4,
                            retry_backoff_ticks=1, shed_threshold=64)
     rng = np.random.default_rng(cfg.seed + 1)
     active = cfg.n_vertices - cfg.reserved

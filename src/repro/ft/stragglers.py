@@ -1,9 +1,9 @@
 """Straggler mitigation for the synchronous tick loop.
 
 Live-wired since ISSUE 9: when the telemetry plane is on
-(`PipelineConfig.telemetry=True`) both pipeline drivers feed
-`StragglerMitigator.observe_tick` every launch — the per-tick wall
-time (super-tick wall / T on the scan driver) plus the per-shard busy
+(`PipelineConfig.telemetry=True`) every launch feeds
+`StragglerMitigator.observe_tick` — the per-tick wall time (the
+launch's wall / T) plus the per-shard busy
 proxies folded from `TickStats.busy` — via
 `D3Pipeline._trace_ticks`; `D3Pipeline.parts_per_shard()` supplies
 the work-steal planner's part map. Before that the class was only

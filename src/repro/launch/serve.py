@@ -55,18 +55,13 @@ def serve_stream(args):
                          window=win.WindowConfig(kind=win.SESSION, interval=4))
     pipe = D3Pipeline(model, params, cfg)
     t0 = time.perf_counter()
-    if args.driver == "super":
-        # device-resident driver: T micro-ticks per lax.scan launch, one
-        # host sync per super-tick (the serving default for throughput)
-        pipe.run_stream_super(edges, feats, tick_edges=args.tick_edges,
-                              super_ticks=args.super_ticks)
-        pipe.flush_super(max_ticks=64, T=4)
-    else:
-        pipe.run_stream(edges, feats, tick_edges=args.tick_edges)
-        pipe.flush()
+    # T micro-ticks per lax.scan launch, one host sync per launch
+    pipe.run_stream_super(edges, feats, tick_edges=args.tick_edges,
+                          super_ticks=args.super_ticks)
+    pipe.flush_super(max_ticks=64, T=4)
     dt = time.perf_counter() - t0
     print(f"streamed {args.edges} edges in {dt:.2f}s "
-          f"[{args.driver} driver, {args.edges / dt:.0f} ev/s]; "
+          f"[T={args.super_ticks}, {args.edges / dt:.0f} ev/s]; "
           f"materialized {len(pipe.embeddings())} embeddings; "
           f"{pipe.metrics.reduce_msgs} RMIs, "
           f"{pipe.metrics.cross_part_msgs} cross-part msgs")
@@ -79,12 +74,9 @@ def main():
     ap.add_argument("--edges", type=int, default=2000)
     ap.add_argument("--tokens", type=int, default=32)
     ap.add_argument("--reduced", action="store_true")
-    ap.add_argument("--driver", choices=["super", "tick"], default="super",
-                    help="super: lax.scan super-tick driver (default); "
-                         "tick: per-tick reference driver")
     ap.add_argument("--tick-edges", type=int, default=256)
     ap.add_argument("--super-ticks", type=int, default=16,
-                    help="micro-ticks per device launch (super driver)")
+                    help="micro-ticks per device launch")
     args = ap.parse_args()
     if args.arch == "d3gnn-sage":
         serve_stream(args)

@@ -227,27 +227,27 @@ def add_query_stats(a: QueryStats, b: QueryStats) -> QueryStats:
     return jax.tree.map(jnp.add, a, b)
 
 
-def empty_query_batch(cap: int, d: int, device: bool = True) -> QueryBatch:
-    conv = jnp.asarray if device else (lambda a: a)
-    zi = conv(np.zeros((cap,), np.int32))
-    zb = conv(np.zeros((cap,), bool))
+def empty_query_batch(cap: int, d: int) -> QueryBatch:
+    """An admission batch with no query, as numpy leaves (host staging
+    stacks a launch's batches and uploads each field once)."""
+    zi = np.zeros((cap,), np.int32)
+    zb = np.zeros((cap,), bool)
     return QueryBatch(qid=zi, kind=zi, part=zi, slot=zi, part2=zi,
                       slot2=zi, consistent=zb, ok=zb, issue=zi,
-                      vec=conv(np.zeros((cap, d), np.float32)), valid=zb)
+                      vec=np.zeros((cap, d), np.float32), valid=zb)
 
 
-def query_batch_from_numpy(rows: dict, cap: int, d: int,
-                           device: bool = True) -> QueryBatch:
+def query_batch_from_numpy(rows: dict, cap: int, d: int) -> QueryBatch:
     """rows: {qid, kind, part, slot, part2, slot2, consistent, issue}
-    numpy columns (vec is always zero on admission; ok starts True)."""
+    numpy columns (vec is always zero on admission; ok starts True).
+    Returns numpy leaves, as every `*_from_numpy` constructor does."""
     n = len(rows["qid"])
     assert n <= cap, f"query batch overflow: {n} > {cap}"
-    conv = jnp.asarray if device else (lambda a: a)
 
     def pad(a, dtype=np.int32):
         out = np.zeros((cap,), dtype)
         out[:n] = a
-        return conv(out)
+        return out
 
     valid = np.zeros((cap,), bool)
     valid[:n] = True
@@ -257,9 +257,8 @@ def query_batch_from_numpy(rows: dict, cap: int, d: int,
                       part=pad(rows["part"]), slot=pad(rows["slot"]),
                       part2=pad(rows["part2"]), slot2=pad(rows["slot2"]),
                       consistent=pad(rows["consistent"], bool),
-                      ok=conv(ok), issue=pad(rows["issue"]),
-                      vec=conv(np.zeros((cap, d), np.float32)),
-                      valid=conv(valid))
+                      ok=ok, issue=pad(rows["issue"]),
+                      vec=np.zeros((cap, d), np.float32), valid=valid)
 
 
 # ===================================================== device-side stages

@@ -1,17 +1,15 @@
 """Host-side serving loop: interleave graph updates and point queries.
 
 `ServeSession` wraps a query-enabled `D3Pipeline` (cfg.query_cap > 0) and
-drives EITHER pipeline driver with queries aboard:
-
-  * driver="tick"  — per-tick reference path: queued submissions admit in
-    the very next micro-tick (`advance(edges, feats)`);
-  * driver="super" — the donated super-tick `lax.scan`: `advance_super`
-    stages T update micro-ticks and spreads the queued submissions over
-    them, so queries admit while updates are still flowing through the
-    same device launch. Answers come back in the launch's single host
-    sync, which a launch that admits a query (or runs while one is held)
-    takes at once; a query-free launch leaves its sync pending until the
-    next launch has been staged (`D3Pipeline.run_super_tick`).
+drives its super-tick launches with queries aboard: `advance_super`
+stages T update micro-ticks and spreads the queued submissions over them,
+so queries admit while updates are still flowing through the same device
+launch (`advance` is the one-tick launch: queued submissions admit in the
+very next micro-tick). Answers come back in the launch's single host
+sync, which a launch that admits a query (or runs while one is held)
+takes at once; a query-free launch leaves its sync pending until the
+next launch has been staged (`D3Pipeline.run_super_tick`). `step` and
+`flush` launch `super_ticks` ticks at a time.
 
 The session keeps the host-side truth the device never sees: wall-clock
 enqueue times per qid. Every harvested answer gets an end-to-end
@@ -88,8 +86,10 @@ class _PendingMeta:
 @dataclass
 class ServeSession:
     pipe: object                                   # a query-enabled D3Pipeline
-    driver: str = "super"                          # "super" | "tick"
+    driver: str = "super"                          # only "super"; kept for
+                                                   # callers that name it
     super_ticks: int = 8                           # T per device launch
+                                                   # (1 = a tick a launch)
     qid_base: int = 0                              # first qid this session
                                                    # assigns — hand over the
                                                    # previous session's
@@ -131,8 +131,12 @@ class ServeSession:
                 "ServeSession needs a query-enabled pipeline: set "
                 "PipelineConfig.query_cap > 0 (the query plane is "
                 "compiled away at query_cap=0)")
-        if self.driver not in ("super", "tick"):
-            raise ValueError(f"driver={self.driver!r}: 'super' or 'tick'")
+        if self.driver != "super":
+            raise ValueError(f"driver={self.driver!r}: only 'super' (set "
+                             "super_ticks=1 for a tick a launch)")
+        if self.super_ticks < 1:
+            raise ValueError(f"super_ticks={self.super_ticks} must be >= 1 "
+                             "(1 = one micro-tick a launch)")
         if self.max_retained <= 0:
             raise ValueError(
                 f"max_retained={self.max_retained} must be > 0 (it bounds "
@@ -219,25 +223,19 @@ class ServeSession:
 
     # ------------------------------------------------------------ advance
     def advance(self, edges=None, feats=None, window=None):
-        """One micro-tick (driver='tick'): queued submissions admit now,
-        up to the per-tick admission budget (the rest stay queued)."""
-        cap = self.pipe.cfg.capacities().query_admissions
-        self._release_due_retries()
-        q = self._take(cap)
-        if self._degraded is not None:
-            self.counters["degraded_ticks"] += 1
-        stats = self.pipe.tick(edges, feats, window=window,
-                               queries=q or None)
-        self._harvest()
-        return stats
+        """One micro-tick: `advance_super` at T=1, read at once. Queued
+        submissions admit now, up to the per-tick admission budget (the
+        rest stay queued). Returns the tick's per-layer TickStats."""
+        return list(self.advance_super(
+            [edges] if edges is not None else None,
+            [feats] if feats is not None else None, T=1, window=window)[0])
 
     def advance_super(self, edge_chunks=None, feat_chunks=None,
                       T=None, window=None, quiet0: int = 0):
-        """One super-tick (driver='super'): queued submissions spread
-        over the launch's T micro-ticks (earliest first, at most
+        """One super-tick: queued submissions spread over the launch's T
+        micro-ticks (earliest first, at most
         `capacities().query_admissions` per tick), so admission
-        interleaves with
-        the update stream on device. Submissions beyond the launch's
+        interleaves with the update stream on device. Submissions beyond the launch's
         admission budget stay queued for the next advance — they never
         overflow a tick's fixed-capacity query batch.
 
@@ -264,21 +262,16 @@ class ServeSession:
         return out
 
     def step(self, edges=None, feats=None, **kw):
-        """Driver-agnostic advance: one tick or one super-tick."""
-        if self.driver == "tick":
-            return self.advance(edges, feats, **kw)
+        """One launch of `super_ticks` micro-ticks, the first carrying
+        `edges` and `feats`."""
         e = [edges] if edges is not None else None
         f = [feats] if feats is not None else None
         return self.advance_super(e, f, T=self.super_ticks, **kw)
 
     def flush(self, max_ticks: int = 128):
-        """Drain the pipeline (and any held consistent queries answer at
-        the first silent tick)."""
-        if self.driver == "tick":
-            ran = self.pipe.flush(max_ticks=max_ticks)
-        else:
-            ran = self.pipe.flush_super(max_ticks=max_ticks,
-                                        T=self.super_ticks)
+        """Drain the pipeline, `super_ticks` ticks a launch (any held
+        consistent queries answer at the first silent tick)."""
+        ran = self.pipe.flush_super(max_ticks=max_ticks, T=self.super_ticks)
         self._harvest()
         return ran
 

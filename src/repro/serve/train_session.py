@@ -2,15 +2,13 @@
 
 `TrainSession` is the training-plane twin of `ServeSession`: it wraps a
 training-enabled `D3Pipeline` (cfg.train_cap > 0 + a `TrainConfig`) and
-drives EITHER pipeline driver with label admissions aboard:
-
-  * driver="tick"  — per-tick reference path: queued labels admit in the
-    very next micro-tick (`advance(edges, feats)`);
-  * driver="super" — the donated super-tick `lax.scan`: `advance_super`
-    stages T update micro-ticks and spreads the queued labels over them,
-    so the windowed online training step (fire-masked backprop +
-    Algorithm 3) runs inside the same device launch as the update
-    stream — still ONE host sync per super-tick.
+drives its super-tick launches with label admissions aboard:
+`advance_super` stages T update micro-ticks and spreads the queued labels
+over them, so the windowed online training step (fire-masked backprop +
+Algorithm 3) runs inside the same device launch as the update stream —
+still ONE host sync per super-tick. `advance` is the one-tick launch
+(queued labels admit in the very next micro-tick); `step` and `flush`
+launch `super_ticks` ticks at a time.
 
 Labels queue host-side until a tick has budget (`capacities().train_cap`
 per tick); vids the partitioner has never seen are silently dropped at
@@ -23,14 +21,12 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 
-import numpy as np
-
 
 @dataclass
 class TrainSession:
     pipe: object                                 # a training-enabled D3Pipeline
-    driver: str = "super"                        # "super" | "tick"
     super_ticks: int = 8                         # T per device launch
+                                                 # (1 = a tick a launch)
     _queue: list = field(default_factory=list)   # un-admitted (vid, gold)
 
     def __post_init__(self):
@@ -40,8 +36,9 @@ class TrainSession:
                 "PipelineConfig.train_cap > 0 and pass "
                 "D3Pipeline(..., train=TrainConfig(...)) (the training "
                 "plane is compiled away at train_cap=0)")
-        if self.driver not in ("super", "tick"):
-            raise ValueError(f"driver={self.driver!r}: 'super' or 'tick'")
+        if self.super_ticks < 1:
+            raise ValueError(f"super_ticks={self.super_ticks} must be >= 1 "
+                             "(1 = one micro-tick a launch)")
 
     # ------------------------------------------------------------- labels
     def observe_labels(self, labels):
@@ -54,19 +51,19 @@ class TrainSession:
 
     # ------------------------------------------------------------ advance
     def advance(self, edges=None, feats=None, window=None):
-        """One micro-tick (driver='tick'): queued labels admit now, up to
-        the per-tick label budget (the rest stay queued)."""
-        cap = self.pipe.cfg.capacities().train_cap
-        l, self._queue = self._queue[:cap], self._queue[cap:]
-        return self.pipe.tick(edges, feats, window=window,
-                              labels=l or None)
+        """One micro-tick: `advance_super` at T=1, read at once. Queued
+        labels admit now, up to the per-tick label budget (the rest stay
+        queued). Returns the tick's per-layer TickStats."""
+        return list(self.advance_super(
+            [edges] if edges is not None else None,
+            [feats] if feats is not None else None, T=1, window=window)[0])
 
     def advance_super(self, edge_chunks=None, feat_chunks=None,
                       T=None, window=None, quiet0: int = 0):
-        """One super-tick (driver='super'): queued labels spread over the
-        launch's T micro-ticks (earliest first, at most
-        `capacities().train_cap` per tick), interleaving label ingest
-        with the update stream on device. Labels beyond the launch's
+        """One super-tick: queued labels spread over the launch's T
+        micro-ticks (earliest first, at most `capacities().train_cap` per
+        tick), interleaving label ingest with the update stream on
+        device. Labels beyond the launch's
         admission budget stay queued — they never overflow a tick's
         fixed-capacity label batch."""
         edge_chunks = list(edge_chunks) if edge_chunks is not None else []
@@ -81,29 +78,22 @@ class TrainSession:
                                         label_chunks=l_chunks)
 
     def step(self, edges=None, feats=None, **kw):
-        """Driver-agnostic advance: one tick or one super-tick."""
-        if self.driver == "tick":
-            return self.advance(edges, feats, **kw)
+        """One launch of `super_ticks` micro-ticks, the first carrying
+        `edges` and `feats`."""
         e = [edges] if edges is not None else None
         f = [feats] if feats is not None else None
         return self.advance_super(e, f, T=self.super_ticks, **kw)
 
     def flush(self, max_ticks: int = 128):
-        """Drain the pipeline: the label backlog admits first (labels
-        only enter with tick budget), then the normal flush runs until
-        device quiescence — so the final fire at the quiescent fixed
-        point sees every label submitted so far."""
+        """Drain the pipeline, `super_ticks` ticks a launch: the label
+        backlog admits first (labels only enter with tick budget), then
+        the normal flush runs until device quiescence — so the final fire
+        at the quiescent fixed point sees every label submitted so far."""
         ran = 0
         while self._queue and ran < max_ticks:
-            if self.driver == "tick":
-                self.advance()
-                ran += 1
-            else:
-                self.advance_super(T=self.super_ticks)
-                ran += self.super_ticks
+            self.advance_super(T=self.super_ticks)
+            ran += self.super_ticks
         remaining = max(max_ticks - ran, 8)
-        if self.driver == "tick":
-            return ran + self.pipe.flush(max_ticks=remaining)
         return ran + self.pipe.flush_super(max_ticks=remaining,
                                            T=self.super_ticks)
 

@@ -46,12 +46,12 @@ Host columns (`TRACE_HOST_COLS`):
 
   tick       : stream clock at the START of the row's tick
   ticks      : micro-ticks this row covers (1; kept for forward compat)
-  wall_s     : wall seconds attributed to the tick (per-tick driver:
-               the measured round; scan driver: super-tick wall / T)
-  host_s     : host-side staging seconds (the d3.stage span; scan
-               driver: the launch's staging / T)
-  amortized  : 1 when wall_s and host_s are super-tick averages, 0
-               when measured per tick
+  wall_s     : wall seconds attributed to the tick (its launch's wall
+               / T: the measured round for a one-tick launch)
+  host_s     : host-side staging seconds (the d3.stage span of its
+               launch / T)
+  amortized  : 1 when wall_s and host_s are averages over a launch of
+               T > 1 ticks, 0 when measured for one tick
   wire_bytes : exact bytes on the wire this tick (host-side static
                arithmetic, `D3Pipeline._static_wire_bytes`)
   edges_in / feats_in / queries_in / labels_in : ingest counts

@@ -99,7 +99,7 @@ def _build_pipe(case, window):
 
 @pytest.fixture(scope="session")
 def streamed_pipeline(stream_case):
-    """stream_case fully streamed (per-tick driver) + flushed, STREAMING
+    """stream_case fully streamed (one tick a launch) + flushed, STREAMING
     policy. READ-ONLY: do not tick or mutate it."""
     from repro.core import windowing as win
     model, params, pipe = _build_pipe(
@@ -112,7 +112,7 @@ def streamed_pipeline(stream_case):
 
 @pytest.fixture(scope="session")
 def super_streamed_pipeline(stream_case):
-    """Same stream driven by the super-tick driver. READ-ONLY."""
+    """Same stream, T=k ticks a launch. READ-ONLY."""
     from repro.core import windowing as win
     model, params, pipe = _build_pipe(
         stream_case, win.WindowConfig(kind=win.STREAMING))

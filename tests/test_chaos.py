@@ -3,8 +3,8 @@ injection scenarios, and degraded-mode serving.
 
 The reshard goldens pin the headline invariant: an UNCAPPED 1-D run is
 bit-equal across ANY device count (canonical delivery order), so a
-mid-stream `D3Pipeline.reshard` — in either direction, under either
-driver, with in-flight windows, defer rings, and held consistent
+mid-stream `D3Pipeline.reshard` — in either direction, one tick a launch
+or several, with in-flight windows, defer rings, and held consistent
 queries — must leave the flushed sink bit-equal to the local
 single-device run, with identical logical integer stats and zero drops.
 
@@ -67,6 +67,7 @@ def _build(D, S=1, n=32, d_in=8, **cfg_kw):
 
 
 def _feed(pipe, edges, feats, driver, tick_edges=16):
+    """driver "tick": one micro-tick a launch; "super": one launch."""
     chunks = [edges[i:i + tick_edges]
               for i in range(0, len(edges), tick_edges)]
     rows = [[(int(v), feats[int(v)]) for e in c for v in set(map(int, e))]
@@ -103,7 +104,7 @@ def golden_case():
 @pytest.mark.parametrize("d_old,d_new", [(4, 2), (2, 4)],
                          ids=["down", "up"])
 def test_reshard_mid_stream_golden(golden_case, driver, d_old, d_new):
-    """Mid-stream reshard (scale-down AND scale-up, both drivers) with
+    """Mid-stream reshard (scale-down AND scale-up, T=1 and T=k) with
     in-flight windows: the flushed sink is BIT-equal to the local run and
     every logical integer stat matches exactly. Nothing dropped."""
     from repro.launch.mesh import make_stream_mesh
@@ -196,15 +197,14 @@ def test_straggler_remap_on_stage_grid():
 
 # ----------------------------------------------------- chaos scenarios
 @needs4
-@pytest.mark.parametrize("driver", ["tick", "super"])
-def test_chaos_failstop_recovery_bit_equal(tmp_path, driver):
+def test_chaos_failstop_recovery_bit_equal(tmp_path):
     """The ISSUE 10 acceptance scenario: hub-heavy spike + fail-stop loss
     of 2/4 shards mid-stream -> checkpoint-restore + reshard onto the
     survivor mesh + replay. dropped == 0, route_dropped == 0, the held
     consistent answers are bit-equal to the uninterrupted oracle's, and
     the post-recovery sink is bit-equal to the oracle run."""
     from repro.ft.chaos import ChaosConfig, scenario_failstop
-    rep = scenario_failstop(ChaosConfig(driver=driver), tmp_path)
+    rep = scenario_failstop(ChaosConfig(), tmp_path)
     assert rep["dropped"] == 0 and rep["route_dropped"] == 0
     np.testing.assert_array_equal(rep["oracle_sink"], rep["chaos_sink"])
     assert rep["oracle_answers"] and (set(rep["oracle_answers"])
@@ -262,7 +262,8 @@ def test_chaos_admission_storm_degrades_observably():
 def _serve(**kw):
     from repro.ft.chaos import ChaosConfig, build_pipeline
     from repro.serve.session import ServeSession
-    return ServeSession(build_pipeline(ChaosConfig()), driver="tick", **kw)
+    return ServeSession(build_pipeline(ChaosConfig()), super_ticks=1,
+                        **kw)
 
 
 def _tick_edges(session, edges, feats):

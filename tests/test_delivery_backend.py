@@ -3,7 +3,7 @@
 `delivery_backend="pallas"` (sorted segment-reduce kernels, interpret
 mode off-TPU) must be indistinguishable from `delivery_backend="xla"`
 (the reference scatters): same embeddings, same exact integer TickStats,
-same busy vector — across all four window policies, both drivers, and
+same busy vector — across all four window policies, T=1 and T=k, and
 both routers. The xla pipelines are themselves pinned to the static
 oracle by tests/test_mesh_router.py, so pallas ≡ xla ≡ oracle.
 
@@ -116,7 +116,7 @@ def test_pipeline_resolves_backend():
 @pytest.mark.parametrize("window", ALL_POLICIES,
                          ids=[w.kind for w in ALL_POLICIES])
 def test_pallas_golden_matrix_local(window):
-    """pallas ≡ xla for BOTH drivers under the LocalRouter, per policy."""
+    """pallas ≡ xla at T = 1 and T = k under the LocalRouter, per policy."""
     edges, feats = make_stream()
     ref = run_per_tick(build_pipe(window, "xla"), edges, feats)
     per = run_per_tick(build_pipe(window, "pallas"), edges, feats)

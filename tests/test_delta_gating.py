@@ -5,7 +5,7 @@ Three contracts pinned here:
   * EXACT mode — `delta_eps=0` (the default) is bit-for-bit the ungated
     PR 5 program: identical embeddings (assert_array_equal, not
     allclose), identical integer TickStats, suppressed == 0 — across all
-    four window policies, both drivers, and both routers (the golden
+    four window policies, T=1 and T=k, and both routers (the golden
     matrix the delivery/router suites use, plus the delta_eps lane).
 
   * APPROXIMATE mode — at eps > 0 a sub-eps update stream is (a) largely

@@ -225,7 +225,7 @@ def test_drivers_feed_straggler_mitigator():
                          max_nodes=40, telemetry=True)
     pipe = D3Pipeline(model, params, cfg)
     assert pipe.straggler is not None and pipe.straggler.ticks_observed == 0
-    pipe.run_stream(edges[:48], feats, tick_edges=16)     # per-tick driver
+    pipe.run_stream(edges[:48], feats, tick_edges=16)     # one tick a launch
     n1 = pipe.straggler.ticks_observed
     assert n1 == 3 and pipe.straggler._ewma > 0.0
     pipe.run_super_tick(T=4)                              # scan driver

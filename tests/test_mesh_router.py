@@ -3,7 +3,7 @@
 `MeshRouter` (part axis block-sharded over a ("data",) mesh, fixed-capacity
 all_to_all delivery) must be indistinguishable from `LocalRouter` (flat
 scatter, one device): same embeddings, same integer TickStats, same busy
-vector — in BOTH drivers, across all four window policies, and both must
+vector — at T = 1 and T = k, across all four window policies, and both must
 match the static oracle.
 
 Three execution tiers:

@@ -83,7 +83,7 @@ def test_deferred_launches_reach_the_same_state():
     """Two rounds of three launches and a flush: a serving session
     (every launch deferred) against the same launches read at once."""
     edges, feats = make_stream(seed=1)
-    deferred = ServeSession(build(), driver="super", super_ticks=T)
+    deferred = ServeSession(build(), super_ticks=T)
     sync = build()
     plan = launches(sync, edges, feats)
     assert len(plan) == 6
@@ -153,7 +153,7 @@ def _serve(read_at_once: bool):
     query is answered. Returns the session and, after every launch, the
     answered qids and the session's counters."""
     edges, feats = make_stream(seed=4)
-    s = ServeSession(build(), driver="super", super_ticks=T, max_retries=2,
+    s = ServeSession(build(), super_ticks=T, max_retries=2,
                      retry_backoff_ticks=2)
     half = len(edges) // 2
     late = sorted(set(np.unique(edges[half:]).tolist())

@@ -5,7 +5,7 @@ Contracts pinned here:
   * stage=1 is the UNTOUCHED 1-D program: a `PipelineConfig(n_stages=1)`
     pipeline on a 1-device mesh is bit-for-bit (`assert_array_equal`,
     exact integer stats) the LocalRouter reference across all four
-    window policies and both drivers — the refactor (psum_vote /
+    window policies and T=1 and T=k — the refactor (psum_vote /
     extra_work plumbing shared with the pipelined path) must be
     HLO-invisible at stage=1.
 
@@ -18,7 +18,7 @@ Contracts pinned here:
 
   * the inter-stage ring is real pending work: it is non-empty mid-
     stream, `flush`/`flush_super` refuse to terminate over it, and it is
-    EMPTY at quiescence (both drivers).
+    EMPTY at quiescence (T=1 and T=k).
 
   * fail-loud config plane: every invalid (mesh, n_stages, layer-stack)
     combination raises a clear ValueError instead of misrouting.
@@ -150,7 +150,7 @@ def test_nonuniform_layer_stack_rejected():
 def test_stage1_golden_bit_identity(window):
     """n_stages=1 on a mesh must stay BIT-identical to the LocalRouter
     1-D program — embeddings via assert_array_equal and exact integer
-    stats, both drivers. Pins that the hybrid-parallel refactor is
+    stats, T=1 and T=k. Pins that the hybrid-parallel refactor is
     unreachable (not just numerically harmless) at stage=1."""
     edges, feats, _, _, ref = run_ref(window)
     e_ref = ref.embeddings()
@@ -186,7 +186,7 @@ def test_stage1_golden_bit_identity(window):
                          ids=[w.kind for w in ALL_POLICIES])
 def test_stage2_golden_matrix(window):
     """stage=2 x data=1: schedule-skewed, but the quiescent state equals
-    the LocalRouter reference and the static oracle — both drivers,
+    the LocalRouter reference and the static oracle — T=1 and T=k,
     exact integer aggregator counts."""
     edges, feats, model, params, ref = run_ref(window)
     e_ref = ref.embeddings()
@@ -382,7 +382,7 @@ def test_stage2_data2_capped_route_backpressure():
                          ids=[w.kind for w in ALL_POLICIES])
 def test_stage2_data4_golden_matrix(window):
     """The ISSUE target shape — stage=2 x data=4 — over every window
-    policy (super-tick driver; the CI pipeline lane runs this
+    policy (T=k launches; the CI pipeline lane runs this
     in-process on a forced 8-device CPU backend)."""
     edges, feats, model, params, ref = run_ref(window)
     mesh = make_stream_mesh(8, stage=2)

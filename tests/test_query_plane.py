@@ -85,7 +85,9 @@ def query_mix(edges):
 
 
 def run_config(edges, feats, mesh, driver, backend):
-    """Stream 3 update ticks, admit the query mix on tick 4, flush."""
+    """Stream 3 update ticks, admit the query mix on tick 4, flush —
+    driver "tick" one tick a launch (`tick`, `flush`), "super" all four
+    ticks in one launch and flushing four a launch."""
     _, _, pipe = build_pipe(mesh=mesh, backend=backend)
     e_chunks, f_chunks = chunked(edges, feats)
     q = query_mix(edges)
@@ -116,9 +118,9 @@ def test_config_validation():
     with pytest.raises(ValueError, match="query plane is disabled"):
         PipelineConfig(query_cap=0, query_tick_cap=8).validate()
     cfg = PipelineConfig(query_cap=8)
-    assert cfg.query_admissions() == 8 * cfg.n_parts
-    assert PipelineConfig(query_cap=8,
-                          query_tick_cap=16).query_admissions() == 16
+    assert cfg.capacities().query_admissions == 8 * cfg.n_parts
+    assert PipelineConfig(
+        query_cap=8, query_tick_cap=16).capacities().query_admissions == 16
 
 
 def test_admission_fills_free_slots_and_drops_overflow():
@@ -145,6 +147,16 @@ def test_queries_require_enabled_plane():
         pipe.tick(queries=[(1, KIND_EMBED, 0, False)])
     with pytest.raises(ValueError, match="query_cap > 0"):
         ServeSession(pipe)
+
+
+def test_serve_session_rejects_bad_launch_size():
+    """A session launches `super_ticks` >= 1 ticks at a time; the old
+    per-tick driver name points at super_ticks=1."""
+    _, _, pipe = build_pipe()
+    with pytest.raises(ValueError, match="super_ticks"):
+        ServeSession(pipe, super_ticks=0)
+    with pytest.raises(ValueError, match="super_ticks=1"):
+        ServeSession(pipe, driver="tick")
 
 
 def test_read_nodes_partial_gather_and_embeddings_wrapper():
@@ -197,7 +209,7 @@ def test_session_budgets_submission_bursts():
                          query_cap=8, query_tick_cap=4,
                          window=win.WindowConfig(kind=win.STREAMING))
     pipe = D3Pipeline(model, params, cfg)
-    s = ServeSession(pipe, driver="super", super_ticks=2)
+    s = ServeSession(pipe, super_ticks=2)
     e_chunks, f_chunks = chunked(edges, feats)
     s.advance_super(e_chunks, f_chunks)            # ingest everything first
     vids = [int(edges[i % len(edges), 0]) for i in range(13)]
@@ -305,7 +317,7 @@ def assert_answers_match(ref, other, name):
 
 @pytest.fixture(scope="module")
 def golden_ref():
-    """The reference config's answers: LocalRouter, per-tick driver, xla.
+    """The reference config's answers: LocalRouter, one-tick launches, xla.
     Built once; every matrix cell compares against it."""
     edges, feats = make_stream()
     _, ref = run_config(edges, feats, None, "tick", "xla")
@@ -323,7 +335,7 @@ MATRIX = [("tick", "xla", "mesh1"), ("super", "xla", "local"),
 
 @pytest.mark.parametrize("driver,backend,where", MATRIX)
 def test_golden_serving_matrix(golden_ref, driver, backend, where):
-    """{LocalRouter, MeshRouter} x {per-tick, super-tick} x {xla, pallas}:
+    """{LocalRouter, MeshRouter} x {T=1, T=k launches} x {xla, pallas}:
     identical answered qids, EXACT answer ticks, equivalent payloads.
     The in-process mesh is the degenerate 1-device one (full shard_map +
     MeshRouter machinery); @needs4 below re-runs on real 4 devices."""
@@ -349,9 +361,9 @@ def test_serve_session_both_drivers():
     edges, feats = make_stream()
     e_chunks, f_chunks = chunked(edges, feats)
     results = {}
-    for driver in ("tick", "super"):
+    for driver in ("tick", "super"):      # T = 1 against T = 4 launches
         _, _, pipe = build_pipe()
-        s = ServeSession(pipe, driver=driver, super_ticks=4)
+        s = ServeSession(pipe, super_ticks=1 if driver == "tick" else 4)
         s.submit_embed([0], consistent=False)
         s.submit_embed([5], consistent=True)
         s.submit_link([(int(edges[0, 0]), int(edges[0, 1]))],
@@ -367,7 +379,7 @@ def test_serve_session_both_drivers():
         assert stats["answered"] == 3
         assert stats["p99_ms"] >= stats["p50_ms"] >= 0
         results[driver] = s.answers
-    # the two drivers resolve the same queries with the same payloads
+    # both launch sizes resolve the same queries with the same payloads
     assert set(results["tick"]) == set(results["super"])
     for qid, a in results["tick"].items():
         b = results["super"][qid]
@@ -383,7 +395,7 @@ def test_serve_session_latency_stats_single_population():
     filter identically, with adopted answers counted separately."""
     from repro.serve.session import Answer
     _, _, pipe = build_pipe()
-    s = ServeSession(pipe, driver="tick")
+    s = ServeSession(pipe, super_ticks=1)
     # two timed answers (staleness 1, 3) + one ADOPTED answer with a huge
     # staleness that must NOT leak into the percentile population
     s.answers[0] = Answer(qid=0, kind=KIND_EMBED, ok=True,
@@ -400,7 +412,7 @@ def test_serve_session_latency_stats_single_population():
     assert stats["staleness_ticks_max"] == 3          # not the adopted 500
     assert stats["p50_ms"] == pytest.approx(20.0)
     # all-adopted sessions report counts only (no percentile keys)
-    s2 = ServeSession(pipe, driver="tick")
+    s2 = ServeSession(pipe, super_ticks=1)
     s2.answers[9] = Answer(qid=9, kind=KIND_EMBED, ok=True,
                            vec=np.zeros(12, np.float32), score=0.0,
                            issue_tick=0, answer_tick=2, latency_s=None)
@@ -414,7 +426,7 @@ def test_serve_session_answer_retention_bound():
     edges, feats = make_stream()
     e_chunks, f_chunks = chunked(edges, feats)
     _, _, pipe = build_pipe()
-    s = ServeSession(pipe, driver="tick", max_retained=4)
+    s = ServeSession(pipe, super_ticks=1, max_retained=4)
     early = s.submit_embed([0, 1, 2])
     for ch, fe in zip(e_chunks, f_chunks):
         s.advance(ch, fe)
@@ -430,4 +442,4 @@ def test_serve_session_answer_retention_bound():
     assert set(late) <= set(s.answers)
     assert len(set(early) & set(s.answers)) == 1
     with pytest.raises(ValueError, match="max_retained"):
-        ServeSession(pipe, driver="tick", max_retained=0)
+        ServeSession(pipe, super_ticks=1, max_retained=0)

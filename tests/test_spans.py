@@ -1,5 +1,6 @@
 """Host spans, counters and device plane scopes (`telemetry/spans.py`,
-`core/tick.py`, `core/pipeline.py`), on both drivers: the spans' totals
+`core/tick.py`, `core/pipeline.py`), driven one tick a launch ("tick")
+and T ticks a launch ("super"): the spans' totals
 and self times, their launch numbers and nesting in a profiler trace,
 the staging counters, host seconds with the telemetry plane off, the
 `d3.*` op names in the compiled tick, and a compiled program that the
@@ -12,7 +13,6 @@ import re
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -66,14 +66,14 @@ def drive(pipe, driver, edges, feats):
 
 @pytest.fixture(scope="module")
 def driven():
-    """One pipeline per driver, driven over the same tiny stream."""
+    """One pipeline per launch size, driven over the same tiny stream."""
     edges, feats = make_stream()
     return {d: drive(build(), d, edges, feats) for d in DRIVERS}
 
 
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
-    """Host events of each driver's run under the profiler, on the CPU:
+    """Host events of each launch size's run under the profiler, on the CPU:
     {driver: [(name, start_ns, end_ns, stats)]} of the d3.* spans."""
     edges, feats = make_stream(seed=1)
     out = {}
@@ -162,19 +162,17 @@ def test_counters_match_the_staged_batches(driver, monkeypatch):
     edges, feats = make_stream(seed=2)
     pipe = build()
     staged = []
-    name = "_build_batches" if driver == "tick" else "_stage_super_batches"
-    orig = getattr(pipe, name)
+    orig = pipe._stage_super_batches
 
     def keep(*a, **k):
         out = orig(*a, **k)
         staged.append(out)
         return out
 
-    monkeypatch.setattr(pipe, name, keep)
+    monkeypatch.setattr(pipe, "_stage_super_batches", keep)
     drive(pipe, driver, edges, feats)
     m = pipe.metrics
-    fb_at = 3 if driver == "tick" else 0
-    fbs = [b[fb_at] for b in staged]
+    fbs = [b[0] for b in staged]
     assert m.launches == len(staged) == m.spans["d3.launch"].count
     assert m.spans["d3.sync"].count == m.launches
     assert 0 < m.drain_launches < m.launches
@@ -184,7 +182,7 @@ def test_counters_match_the_staged_batches(driver, monkeypatch):
     assert m.feat_rows_staged == len(np.unique(edges))
     cap = pipe.cfg.feat_cap
     assert m.feat_slots_uploaded == sum(fb.valid.size for fb in fbs) \
-        == cap * (m.launches if driver == "tick" else T * m.launches)
+        == cap * (1 if driver == "tick" else T) * m.launches
     assert m.upload_bytes == sum(x.nbytes for b in staged
                                  for x in jax.tree.leaves(b))
 
@@ -256,17 +254,11 @@ def test_cpu_trace_nests_spans_as_placed(traced, driver):
 
 
 def _compiled(driver, pipe):
-    """The compiled text of the driver's device program for `pipe`."""
-    cfg = pipe.cfg
-    if driver == "super":
-        return pl.lower_super_tick(pipe.model, cfg, T).compile().as_text()
-    eb, rb, vb, fb, qb, lb = pipe._build_batches(None, None)
-    return pl._tick_jit.lower(
-        tuple(pipe.layers), pipe.params, pipe.topo, tuple(pipe.states),
-        pipe.sink, pipe.sink_seen, pipe.queries, fb, eb, rb, vb, qb, lb,
-        pipe.train_state, jnp.int32(0), cfg.window, cfg.capacities().outbox,
-        pipe.router, pipe.delivery, pipe.mesh, cfg.delta_eps,
-        pipe.train_cfg, pipe._head, cfg.telemetry).compile().as_text()
+    """The compiled text of the launch program for `pipe`: one tick
+    ("tick") or T ticks ("super") a launch."""
+    ticks = 1 if driver == "tick" else T
+    return pl.lower_super_tick(pipe.model, pipe.cfg,
+                               ticks).compile().as_text()
 
 
 @contextlib.contextmanager
@@ -291,7 +283,7 @@ def test_compiled_tick_carries_the_plane_scopes(driver):
                   "d3.layer1/d3.deliver", "d3.topo", "d3.sink",
                   "d3.query"):
         assert any(scope in n for n in names), scope
-    assert any("d3.quiet" in n for n in names) == (driver == "super")
+    assert any("d3.quiet" in n for n in names)
 
 
 @pytest.mark.parametrize("driver", DRIVERS)

@@ -1,9 +1,9 @@
-"""Golden equivalence of the two pipeline drivers (ISSUE 1 tentpole).
+"""Golden equivalence of T-tick and one-tick launches (ISSUE 1 tentpole).
 
-The super-tick driver (`run_super_tick`: one jitted `lax.scan` over T
+A launch of T micro-ticks (`run_super_tick`: one jitted `lax.scan` over T
 micro-ticks x L layers) must produce the SAME materialized embeddings as
-the per-tick reference driver (`tick()`), and both must match the static
-oracle on the final snapshot — across all four window policies.
+T one-tick launches (`tick()`, `run_stream`, `flush`), and both must match
+the static oracle on the final snapshot — across all four window policies.
 """
 import numpy as np
 import jax
@@ -120,7 +120,7 @@ def test_flush_super_reports_quiescence():
 
 def test_stacked_batches_pad_short_super_tick():
     """Fewer staged ticks than T: the tail is padded with empty ticks and
-    the embeddings still match the per-tick reference."""
+    the embeddings still match T=1 launches."""
     edges, feats = make_stream(seed=7, n_edges=64)
     w = win.WindowConfig(kind=win.STREAMING)
     _, _, ref = build_pipe(w)

@@ -57,7 +57,8 @@ def build_pipe(window=None, telemetry=False, mesh=None, **cfg_kw):
 
 def drive(pipe, e_chunks, f_chunks, driver):
     """Fixed tick sequence (chunks + FLUSH_TICKS empty ticks) so every
-    pipeline in a test sees identical tick boundaries."""
+    pipeline in a test sees identical tick boundaries: one tick a launch
+    ("tick") or all of them in two launches ("super")."""
     if driver == "tick":
         for e, f in zip(e_chunks, f_chunks):
             pipe.tick(e, f)
@@ -76,8 +77,9 @@ def drive(pipe, e_chunks, f_chunks, driver):
                          ids=[w.kind for w in ALL_POLICIES])
 def test_occupancy_exactness_local(window, driver, tmp_path):
     """Every per-plane occupancy column equals the exact integer count
-    from the plain (telemetry=False) per-tick LocalRouter run, on both
-    drivers, and the traced pipeline's numerics are bit-identical."""
+    from the plain (telemetry=False) one-tick-a-launch LocalRouter run,
+    at T = 1 and T = k, and the traced pipeline's numerics are
+    bit-identical."""
     edges, feats = make_stream()
     _, _, ref = build_pipe(window)
     e_chunks, f_chunks = ref.chunk_stream(edges, feats, 24)
@@ -142,6 +144,74 @@ def test_occupancy_exactness_local(window, driver, tmp_path):
     back = load_trace(tmp_path / "t.npz")
     for c in TRACE_DEVICE_COLS:
         np.testing.assert_array_equal(back.col(c), cols[c])
+
+
+# The trace rows the per-tick `tick()` wrote for this stream (four chunks
+# of 24 edges and one empty tick, STREAMING window) before a one-tick
+# launch went through `run_super_tick`. Every device column not listed
+# was 0 on every row.
+TICK_ROWS = {
+    "emitted_final": [27, 29, 25, 30, 0],
+    "emitted_sum": [54, 56, 45, 53, 0],
+    "reduce_msgs": [48, 101, 99, 129, 0],
+    "broadcast_msgs": [24, 39, 32, 43, 0],
+    "outbox_demand": [27, 29, 25, 30, 0],
+    "outbox_part_peak": [7, 10, 8, 10, 0],
+    "tick": [0, 1, 2, 3, 4],
+    "ticks": [1, 1, 1, 1, 1],
+    "amortized": [0, 0, 0, 0, 0],
+    "wire_bytes": [0, 0, 0, 0, 0],
+    "edges_in": [24, 24, 24, 23, 0],
+    "feats_in": [27, 5, 0, 0, 0],
+    "queries_in": [0, 0, 0, 0, 0],
+    "labels_in": [0, 0, 0, 0, 0],
+}
+
+
+def test_one_tick_launch_writes_the_tick_row():
+    """`run_super_tick(T=1)` with the telemetry plane on writes, for each
+    launch, the trace row the per-tick `tick()` wrote (`TICK_ROWS`): one
+    tick, not amortized (a launch of one tick has nothing to spread), and
+    device columns equal to the exact integer counts of an untraced run
+    of the same launches."""
+    edges, feats = make_stream()
+    _, _, off = build_pipe()
+    _, _, one = build_pipe(telemetry=True)
+    e_chunks, f_chunks = one.chunk_stream(edges, feats, 24)
+    ref_rows = []
+    for e, f in zip(e_chunks, f_chunks):
+        ref_rows.append(off.run_super_tick([e], [f], T=1)[0])
+        stats, _ = one.run_super_tick([e], [f], T=1)
+        assert len(stats) == 2
+    ref_rows.append(off.run_super_tick(T=1)[0])
+    one.run_super_tick(T=1)
+    got = one.trace.columns()
+    assert len(one.trace) == len(e_chunks) + 1 == len(TICK_ROWS["tick"])
+    for col in TRACE_DEVICE_COLS:
+        want = TICK_ROWS.get(col, [0] * len(one.trace))
+        np.testing.assert_array_equal(got[col], want, err_msg=col)
+    for col in ("tick", "ticks", "amortized", "wire_bytes", "edges_in",
+                "feats_in", "queries_in", "labels_in"):
+        np.testing.assert_array_equal(got[col], TICK_ROWS[col],
+                                      err_msg=col)
+    exact = {
+        "emitted_final": [int(r[-1].emitted) for r in ref_rows],
+        "emitted_sum": [sum(int(s.emitted) for s in r) for r in ref_rows],
+        "reduce_msgs": [sum(int(s.reduce_msgs) for s in r)
+                        for r in ref_rows],
+        "broadcast_msgs": [sum(int(s.broadcast_msgs) for s in r)
+                           for r in ref_rows],
+        "outbox_demand": [max(int(s.emitted) + int(s.dropped) for s in r)
+                          for r in ref_rows],
+    }
+    for col, want in exact.items():
+        np.testing.assert_array_equal(got[col], want, err_msg=col)
+    assert (got["ticks"] == 1).all() and not got["amortized"].any()
+    np.testing.assert_array_equal(
+        got["edges_in"][:-1], [len(e) for e in e_chunks])
+    assert (got["wall_s"] > 0).all() and (got["host_s"] > 0).all()
+    np.testing.assert_array_equal(np.asarray(one.sink),
+                                  np.asarray(off.sink))
 
 
 def test_query_plane_occupancy_gauges():
@@ -266,8 +336,8 @@ def test_advisor_cli(tmp_path):
 def test_mesh_telemetry_exactness_and_parity(tmp_path):
     """Forced-4 mesh with a capped exchange: the defer-ring gauges equal
     the `defer_occupancy` oracle on the end-of-tick carry, route_peak is
-    live, telemetry on == off bit-for-bit, the super-tick driver's
-    device rows equal the per-tick driver's, and the advisor's
+    live, telemetry on == off bit-for-bit, a T = k launch's device rows
+    equal T = 1 launches', and the advisor's
     recommended caps replay with zero drops and less wire than dense."""
     from repro.launch.mesh import make_stream_mesh
     edges, feats = make_stream(n_edges=140)
@@ -310,7 +380,7 @@ def test_mesh_telemetry_exactness_and_parity(tmp_path):
     assert tel.metrics.emitted_total == off.metrics.emitted_total
     assert tel.metrics.wire_rows == off.metrics.wire_rows
 
-    # super-tick driver: same tick boundaries -> identical device rows
+    # T=k launches: same tick boundaries -> identical device rows
     _, _, sup = build_pipe(telemetry=True, mesh=mesh, **capped)
     drive(sup, e_chunks, f_chunks, "super")
     sup_cols = sup.trace.columns()

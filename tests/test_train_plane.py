@@ -20,7 +20,7 @@ Contracts pinned here:
     snapshot, so the online plane is transitively pinned to autodiff.
 
   * online learning learns: loss decreases over repeated label passes,
-    both drivers, optimizer state advances.
+    T=1 and T=k, optimizer state advances.
 
   * the training state rides the consistent checkpoint cut: a mid-stream
     snapshot restores optimizer state (adam moments + step count) and the
@@ -33,6 +33,7 @@ Contracts pinned here:
     backward_layer_routed). Subprocess smokes force the device counts on
     single-device machines.
 """
+from dataclasses import replace
 from pathlib import Path
 
 import numpy as np
@@ -131,13 +132,13 @@ def test_capacities_view_matches_deprecated_accessors():
     caps = cfg.capacities()
     assert isinstance(caps, Capacities)
     assert caps.train_cap == 8
-    with pytest.deprecated_call():
-        assert cfg.outbox() == caps.outbox
-    with pytest.deprecated_call():
-        assert cfg.query_admissions() == caps.query_admissions
-    with pytest.deprecated_call():
-        assert cfg.defer_rows(cfg.n_parts * cfg.repl_cap, 1) \
-            == caps.bc_defer_rows
+    # the emission budget defaults to feat_cap, split over the parts
+    assert caps.outbox == cfg.feat_cap and caps.outbox_per_part == 32
+    assert caps.query_admissions == 0          # query plane off
+    # one device: no capped exchange, so no defer ring
+    assert caps.bc_defer_rows == caps.rmi_defer_rows == 0
+    capped = replace(cfg, route_cap=8).capacities(n_devices=2)
+    assert capped.bc_defer_rows == 2 * (cfg.n_parts // 2) * cfg.repl_cap
 
 
 def test_train_session_rejects_untrained_pipeline():
@@ -146,8 +147,8 @@ def test_train_session_rejects_untrained_pipeline():
         TrainSession(pipe)
     tcfg = TrainConfig(optimizer=sgd(), batch_threshold=1)
     _, _, tp = build_pipe(STREAMING, train=tcfg, train_cap=8)
-    with pytest.raises(ValueError, match="driver"):
-        TrainSession(tp, driver="warp")
+    with pytest.raises(ValueError, match="super_ticks"):
+        TrainSession(tp, super_ticks=0)
 
 
 def test_training_coordinator_requires_train_config():
@@ -252,7 +253,7 @@ def test_online_training_decreases_loss(driver):
     edges, feats, labels = make_stream()
     tcfg = TrainConfig(optimizer=sgd(), lr=0.1, batch_threshold=4)
     _, _, pipe = build_pipe(STREAMING, train=tcfg, train_cap=64)
-    sess = TrainSession(pipe, driver=driver, super_ticks=4)
+    sess = TrainSession(pipe, super_ticks=1 if driver == "tick" else 4)
     e_chunks, f_chunks = pipe.chunk_stream(edges, feats, 24)
     sess.observe_labels(labels)
     if driver == "tick":
@@ -280,7 +281,7 @@ def test_online_compression_path_learns():
                        compression=True, topk_frac=0.5)
     _, _, pipe = build_pipe(STREAMING, train=tcfg, train_cap=64)
     assert pipe.train_state.residual, "compression must allocate residuals"
-    sess = TrainSession(pipe, driver="tick")
+    sess = TrainSession(pipe, super_ticks=1)
     pipe.run_stream(edges, feats, tick_edges=24)
     sess.observe_labels(labels)
     sess.flush()
